@@ -26,9 +26,9 @@ def _cell(b: int, seq_pages: int, kern: str,
     rng = np.random.RandomState(b * 131 + seq_pages)
     n_pages = b * seq_pages + 8
     q = jnp.asarray(rng.randn(b, HEADS, HEAD_DIM), jnp.float32)
-    kp = jnp.asarray(rng.randn(n_pages, PAGE, KV_HEADS, HEAD_DIM) * 0.3,
+    kp = jnp.asarray(rng.randn(n_pages, KV_HEADS, PAGE, HEAD_DIM) * 0.3,
                      jnp.float32)
-    vp = jnp.asarray(rng.randn(n_pages, PAGE, KV_HEADS, HEAD_DIM) * 0.3,
+    vp = jnp.asarray(rng.randn(n_pages, KV_HEADS, PAGE, HEAD_DIM) * 0.3,
                      jnp.float32)
     tables = jnp.asarray(
         rng.permutation(n_pages)[:b * seq_pages].reshape(b, seq_pages)

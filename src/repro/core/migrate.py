@@ -71,7 +71,9 @@ from repro.core.services.mmu import _share_key
 # once (``{"ppage"}`` entries, no per-seq duplicates), host payloads key
 # by host slot (``"h:<slot>"``), and the MMU snapshot carries per-page
 # host_slot + prefix-index chain hashes so restore rebuilds sharing.
-MIGRATION_STATE_VERSION = 2
+# v3: page payloads are head-major, ``(n_layers, kv_heads, page_size,
+# head_dim)`` per page, following the pools.
+MIGRATION_STATE_VERSION = 3
 
 
 class MigrationError(RuntimeError):

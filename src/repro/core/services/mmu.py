@@ -430,7 +430,7 @@ class MMU(Service):
         """Register the page-data mover for REAL KV migration on evict.
 
         ``gather(ppage)`` returns the page's payload (e.g. the serving
-        engine's (n_layers, page_size, K, hd) KV slab for that physical
+        engine's (n_layers, K, page_size, hd) KV slab for that physical
         page) *before* the device page is freed; ``scatter(ppage, data)``
         writes a preserved payload into a freshly allocated device page
         on fault-back-in.  Without a pager, eviction falls back to the

@@ -25,10 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend bits (absent on some CPU-only installs)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -106,7 +105,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     sm_scale: Optional[float] = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False,
+                    block_k: int = 128, interpret: Optional[bool] = None,
                     return_lse: bool = False):
     """q (B, H, Sq, D); k, v (B, K, Sk, D) -> (B, H, Sq, D).
 
@@ -155,12 +154,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             jax.ShapeDtypeStruct((b, h, q.shape[2]), jnp.float32),
         ]
 
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, d), jnp.float32)]
-    else:  # pragma: no cover
-        scratch = [pl.MemorySpace.ANY((block_q, 1), jnp.float32)] * 2
+    scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, d), jnp.float32)]
 
     res = pl.pallas_call(
         kernel,
@@ -176,7 +172,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     if return_lse:
         out, lse = res

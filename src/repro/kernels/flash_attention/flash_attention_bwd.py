@@ -23,10 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -163,7 +162,7 @@ def _pad_seq(x, block, axis=2):
 def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
                         sm_scale: Optional[float] = None,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = False
+                        interpret: Optional[bool] = None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """q/o/do (B,H,Sq,D); k/v (B,K,Sk,D); lse (B,H,Sq) -> (dq, dk, dv)."""
     b, h, sq, d = q.shape
@@ -180,7 +179,7 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
     nq = q_.shape[2] // block_q
     nk = k_.shape[2] // block_k
 
-    scr = ([pltpu.VMEM((block_q, d), jnp.float32)] if pltpu else [])
+    scr = [pltpu.VMEM((block_q, d), jnp.float32)]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
@@ -204,7 +203,7 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q_.shape, q.dtype),
         scratch_shapes=scr,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_, k_, v_, o_, do_, lse_)[:, :, :sq]
 
     # q-side tensors grouped per kv head for the dkv kernel
@@ -213,8 +212,8 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
     dog = do_.reshape(b, kh, group, q_.shape[2], d)
     lseg = lse_.reshape(b, kh, group, q_.shape[2])
 
-    scr2 = ([pltpu.VMEM((block_k, d), jnp.float32),
-             pltpu.VMEM((block_k, d), jnp.float32)] if pltpu else [])
+    scr2 = [pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32)]
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
@@ -243,6 +242,6 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
         out_shape=[jax.ShapeDtypeStruct(k_.shape, k.dtype),
                    jax.ShapeDtypeStruct(v_.shape, v.dtype)],
         scratch_shapes=scr2,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qg, k_, v_, og, dog, lseg)
     return dq, dk[:, :, :sk], dv[:, :, :sk]

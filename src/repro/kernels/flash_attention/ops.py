@@ -1,9 +1,10 @@
 """Jitted public wrapper for flash attention.
 
 ``mha(...)`` takes the model-layout tensors (B, S, H, D) and dispatches to
-the Pallas kernel (TPU) or the jnp oracle (CPU / debugging).  On this
-container the kernel runs under interpret=True for validation; real
-deployments flip ``use_pallas`` on.
+the Pallas kernel or the jnp oracle.  Both ``use_pallas`` and
+``interpret`` default to what the platform supports (see
+:mod:`repro.kernels`): the compiled kernel on a TPU, the jnp oracle
+elsewhere.
 """
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_use_pallas
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -21,13 +23,14 @@ from repro.kernels.flash_attention.ref import attention_ref
                                              "use_pallas", "interpret",
                                              "block_q", "block_k"))
 def mha(q, k, v, *, causal: bool = True, window: int = 0,
-        use_pallas: bool = False, interpret: bool = True,
+        use_pallas: Optional[bool] = None,
+        interpret: Optional[bool] = None,
         block_q: int = 128, block_k: int = 128):
     """q (B, Sq, H, D); k, v (B, Sk, K, D) -> (B, Sq, H, D)."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if use_pallas:
+    if resolve_use_pallas(use_pallas):
         ot = flash_attention(qt, kt, vt, causal=causal, window=window,
                              block_q=block_q, block_k=block_k,
                              interpret=interpret)
@@ -39,11 +42,11 @@ def mha(q, k, v, *, causal: bool = True, window: int = 0,
 # ------------------------------------------------------------- custom vjp --
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def mha_fused(q, k, v, causal: bool = True, window: int = 0,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     """Differentiable fused attention: Pallas fwd + Pallas bwd kernels.
 
-    Layout (B, H, S, D).  Use inside training code on TPU; interpret mode
-    validates on CPU (tests/test_kernels.py)."""
+    Layout (B, H, S, D).  Compiled on a TPU; interpret mode elsewhere
+    (tests/test_kernels.py)."""
     return flash_attention(q, k, v, causal=causal, window=window,
                            interpret=interpret)
 

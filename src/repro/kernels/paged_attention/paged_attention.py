@@ -3,8 +3,11 @@
 This is the paper-technique kernel: decode attention that reads KV through
 the MMU's page tables (the "TLB lookup" in hardware).  TPU adaptation:
 
-  * KV lives in a paged pool ``(n_pages, page_size, kv_heads, head_dim)``
-    (HBM); sequences own scattered page lists;
+  * KV lives in a head-major paged pool ``(n_pages, kv_heads, page_size,
+    head_dim)`` (HBM); sequences own scattered page lists.  Head-major
+    makes one (page, kv head) block a ``(page_size, head_dim)`` tile
+    whose last two dims Mosaic can DMA at any head count (a token-major
+    pool would need a 1-wide block on the head axis, which v5e refuses);
   * the grid is (batch, kv_heads, page_groups); the group axis is
     sequential, carrying the online-softmax state (m/l/acc) in VMEM
     scratch;
@@ -23,6 +26,8 @@ the MMU's page tables (the "TLB lookup" in hardware).  TPU adaptation:
     entries (-1, e.g. host-swapped pages or empty batch slots) index
     page 0 but stay masked; a page group that is entirely masked
     contributes nothing (the online-softmax update is where-guarded).
+    The page mask is built from the int32 table entries themselves, so
+    no boolean vector is ever concatenated in the kernel.
 
 Oracle: ``ref.py``.
 """
@@ -35,13 +40,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
+# Full float32 contractions: a decode q tile is only ``group`` rows, so the
+# extra MXU passes are few, and they keep the kernel within float32
+# rounding of the reference path at any default matmul precision.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _pa_kernel(tables_ref, lens_ref,           # scalar prefetch (SMEM)
@@ -70,16 +77,18 @@ def _pa_kernel(tables_ref, lens_ref,           # scalar prefetch (SMEM)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)              # (group, d)
         k = jnp.concatenate(
-            [k_refs[j][0, :, 0] for j in range(ppb)],
+            [k_refs[j][0, 0] for j in range(ppb)],
             axis=0).astype(jnp.float32)                  # (ppb*page, d)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=_F32,
             preferred_element_type=jnp.float32) * sm_scale  # (group, ppb*pg)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        page_ok = jnp.concatenate(
-            [jnp.broadcast_to(tables_ref[b, gi * ppb + j] >= 0,
-                              (page_size,)) for j in range(ppb)], axis=0)
-        s = jnp.where((pos < seq_len) & page_ok[None, :], s, NEG_INF)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # table entry of each column's page, selected from SMEM scalars
+        entry = jnp.full(s.shape, -1, jnp.int32)
+        for j in range(ppb):
+            entry = jnp.where(col // page_size == j,
+                              tables_ref[b, gi * ppb + j], entry)
+        s = jnp.where((start + col < seq_len) & (entry >= 0), s, NEG_INF)
 
         m_prev = m_scratch[...]                          # (group, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -90,10 +99,10 @@ def _pa_kernel(tables_ref, lens_ref,           # scalar prefetch (SMEM)
         l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
             p, axis=1, keepdims=True)
         v = jnp.concatenate(
-            [v_refs[j][0, :, 0] for j in range(ppb)],
+            [v_refs[j][0, 0] for j in range(ppb)],
             axis=0).astype(jnp.float32)                  # (ppb*page, d)
         acc_scratch[...] = acc_scratch[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())), precision=_F32,
             preferred_element_type=jnp.float32)
         m_scratch[...] = m_new
 
@@ -113,19 +122,20 @@ def default_pages_per_block(page_size: int, max_pages: int,
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                     sm_scale: Optional[float] = None,
                     pages_per_block: Optional[int] = None,
-                    interpret: bool = False):
+                    interpret: Optional[bool] = None):
     """Decode attention through page tables.
 
     q            (B, H, D)         — one new token per sequence
-    k/v_pages    (P, page, K, D)   — the MMU's device page pool
+    k/v_pages    (P, K, page, D)   — the MMU's device page pool, head-major
     block_tables (B, max_pages)    int32 physical page ids (-1 = unmapped)
     seq_lens     (B,)              int32 valid tokens per sequence
     pages_per_block                pages fetched/processed per grid step
                                    (None = auto-size toward a 128-row tile)
+    interpret                      None = interpret mode exactly off the TPU
     -> (B, H, D)
     """
     b, h, d = q.shape
-    n_pages, page_size, kh, _ = k_pages.shape
+    n_pages, kh, page_size, _ = k_pages.shape
     group = h // kh
     max_pages = block_tables.shape[1]
     if sm_scale is None:
@@ -147,9 +157,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
 
     def _page_spec(j):
         return pl.BlockSpec(
-            (1, page_size, 1, d),
+            (1, 1, page_size, d),
             lambda bi, ki, gi, tables, lens, j=j:
-            (jnp.maximum(tables[bi, gi * ppb + j], 0), 0, ki, 0))
+            (jnp.maximum(tables[bi, gi * ppb + j], 0), ki, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -174,7 +184,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, group, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_tables, seq_lens, qg,
       *([k_pages] * ppb), *([v_pages] * ppb))
     return out.reshape(b, h, d)

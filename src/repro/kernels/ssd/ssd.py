@@ -25,10 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_out_ref,
@@ -82,7 +81,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_out_ref,
 
 
 def ssd_chunked_pallas(x, dt, A, Bm, C, *, chunk: int = 256,
-                       interpret: bool = False
+                       interpret: Optional[bool] = None
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x (B,S,H,P); dt (B,S,H) post-softplus; A (H,); Bm/C (B,S,G,N).
 
@@ -102,7 +101,7 @@ def ssd_chunked_pallas(x, dt, A, Bm, C, *, chunk: int = 256,
     nc = s_pad // chunk
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    scratch = [pltpu.VMEM((pd, n), jnp.float32)] if pltpu is not None else []
+    scratch = [pltpu.VMEM((pd, n), jnp.float32)]
 
     y, st = pl.pallas_call(
         kernel,
@@ -128,6 +127,6 @@ def ssd_chunked_pallas(x, dt, A, Bm, C, *, chunk: int = 256,
             jax.ShapeDtypeStruct((b, h, pd, n), jnp.float32),
         ],
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, A, Bm, C)
     return y[:, :s_len], st

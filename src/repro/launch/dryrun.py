@@ -22,7 +22,6 @@ from pathlib import Path
 
 import jax
 
-from repro.compat import normalize_cost_analysis
 from repro.configs import ALL_SHAPES, ARCHS, get_config, get_shape, shape_applicable
 from repro.launch.mesh import make_production_mesh, mesh_chips
 from repro.launch.steps import make_bundle
@@ -61,7 +60,7 @@ def run_cell(arch_id: str, shape_name: str, mesh_kind: str, *,
 
         mem = R.memory_stats(compiled)
         print(f"[{arch_id}/{shape_name}/{mesh_kind}] memory_analysis:", mem)
-        ca = normalize_cost_analysis(compiled.cost_analysis())
+        ca = compiled.cost_analysis() or {}
         print(f"[{arch_id}/{shape_name}/{mesh_kind}] cost_analysis: "
               f"flops={ca.get('flops', 0):.3e} "
               f"bytes={ca.get('bytes accessed', 0):.3e}")

@@ -3,17 +3,12 @@
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — smoke tests must keep seeing 1 CPU device,
 while the dry-run initialises 512 placeholder devices before calling in.
-
-Mesh construction goes through :mod:`repro.compat` so installs without
-``jax.sharding.AxisType`` (older JAX) still work — Auto is the implicit
-default there.
+Every axis is ``AxisType.Auto``: shardings propagate through GSPMD.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro import compat
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -21,8 +16,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     `pod` axis (512 chips).  DP/FSDP runs on (pod, data); TP/EP/SP on model."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes,
-                            axis_types=compat.auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -46,9 +40,8 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
             "before importing jax (subprocess-style, see "
             "tests/test_mesh_serving.py and docs/sharding.md)")
-    return compat.make_mesh((data, model), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2),
-                            devices=devs)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devs)
 
 
 def mesh_chips(mesh: Mesh) -> int:

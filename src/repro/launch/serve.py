@@ -2,6 +2,10 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
         --requests 16 --max-new 16 --batch 8
+
+Serves the published widths by default; ``--reduced`` serves the 2-layer
+cut used by the CPU tests.  The decode kernel follows the platform: the
+compiled Pallas kernel on a TPU, the XLA reference elsewhere.
 """
 from __future__ import annotations
 
@@ -14,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.services.mmu import MMU, MMUConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import ServingEngine
 
@@ -28,10 +33,15 @@ def main(argv=None) -> int:
     ap.add_argument("--n-pages", type=int, default=512)
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the 2-layer reduced config")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    cache_dir = use_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device: platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())} compile_cache={cache_dir}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -51,6 +61,7 @@ def main(argv=None) -> int:
     lat = [r.t_first_token - r.t_submit for r in eng.completed]
     stats["ttft_p50_s"] = float(np.percentile(lat, 50)) if lat else 0.0
     stats["mmu"] = eng.mmu.utilization()
+    stats["use_pallas"] = eng.use_pallas
     print(json.dumps(stats, indent=1, default=str))
     return 0
 

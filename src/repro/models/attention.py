@@ -197,7 +197,6 @@ def attend_decode_cp(q, k_cache, v_cache, cache_len, mesh, *,
     `model`; cache_len (B,).  Inside shard_map the local block is the
     paged/flash decode Pallas kernel region (fused contract scope).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, _, h, hd = q.shape
@@ -230,12 +229,12 @@ def attend_decode_cp(q, k_cache, v_cache, cache_len, mesh, *,
         out = out / jnp.maximum(l.transpose(0, 3, 1, 2, 4), 1e-30)
         return out.reshape(qb.shape[0], 1, h, hd).astype(qb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bax, None, None, None), P(bax, seq_axis, None, None),
                   P(bax, seq_axis, None, None), P(bax)),
         out_specs=P(bax, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, cache_len)
 
 

@@ -20,7 +20,6 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.services.collectives import CollectiveConfig, CollectiveService
@@ -44,10 +43,10 @@ def make_handoff_fn(mesh, svc: CollectiveService = None, *,
             sent = svc.rdma_write(v, qp, pod_axis=pod_axis)
             idx = jax.lax.axis_index(pod_axis)
             return jnp.where(idx > 0, sent, v)
-        return shard_map(local, mesh=mesh,
-                         in_specs=P(pod_axis),
-                         out_specs=P(pod_axis),
-                         check_rep=False)(x)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=P(pod_axis),
+                             out_specs=P(pod_axis),
+                             check_vma=False)(x)
 
     def handoff(cache):
         return jax.tree.map(_leaf_handoff, cache)

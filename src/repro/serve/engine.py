@@ -63,6 +63,7 @@ from repro.configs.base import ModelConfig
 from repro.core.faults import FaultKind
 from repro.core.port import PortError
 from repro.core.services.mmu import MMU, MMUConfig
+from repro.kernels import resolve_use_pallas
 from repro.serve.paged_model import (bucket_pages, decode_step_paged,
                                      flat_page_indices, gather_kv_pages,
                                      make_pools, prefill_chunk_paged,
@@ -104,7 +105,7 @@ def _bucket(n: int, cap: int) -> int:
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, mmu: MMU, *,
                  max_batch: int = 8, max_len: int = 1024,
-                 use_pallas: bool = False,
+                 use_pallas: Optional[bool] = None,
                  pages_per_block: Optional[int] = None, seed: int = 0,
                  shell=None, slot: int = 0, tenant: Optional[str] = None,
                  rid_base: int = 0, prefill_chunk: Optional[int] = None,
@@ -118,7 +119,9 @@ class ServingEngine:
         self.max_batch = max_batch
         self.max_len = max_len
         self.max_pages = -(-max_len // self.page)
-        self.use_pallas = use_pallas
+        # decode attention: the compiled Pallas kernel on a TPU, the XLA
+        # reference elsewhere, unless the caller says otherwise
+        self.use_pallas = use_pallas = resolve_use_pallas(use_pallas)
         self.pages_per_block = pages_per_block
         # chunked/streaming prefill: prompts whose uncovered suffix
         # exceeds ``prefill_chunk`` tokens are prefilled one chunk per

@@ -7,15 +7,17 @@ its oracle).
 
 Hot-path contract (device-resident decode):
 
-  * **Flat pool layout.**  The pools are a single
-    ``(n_layers * n_pages, page_size, kv_heads, head_dim)`` buffer per
+  * **Flat, head-major pool layout.**  The pools are a single
+    ``(n_layers * n_pages, kv_heads, page_size, head_dim)`` buffer per
     side; layer ``l``'s physical page ``p`` lives at flat slot
     ``l * n_pages + p``.  This lets the pools ride the decode scan as an
     *aliased loop carry* — per-layer KV appends are in-place
     dynamic-updates into one buffer — instead of as scan inputs/outputs,
     which would force a full pool copy every step.  Per-layer access is
     pure page-id arithmetic (bias the block table by ``l * n_pages``), so
-    the paged-attention kernel is unchanged.
+    the paged-attention kernel is unchanged.  Head-major puts one
+    (page, kv head) block in the last two dims as a ``(page_size,
+    head_dim)`` tile, which is what the compiled TPU kernel DMAs.
   * **Donation.**  ``pools`` (and the decode-state buffers lens /
     last_tokens / rng) are donated into the jitted steps — KV is updated
     in place, never copied.  Callers must drop their reference and adopt
@@ -59,23 +61,45 @@ def _count_trace(name: str) -> None:
 
 def make_pools(cfg: ModelConfig, n_pages: int, page_size: int, *,
                dtype=jnp.float32, kv_sharding=None) -> Dict[str, jnp.ndarray]:
-    """Flat KV pools: layer ``l``'s page ``p`` is flat slot
-    ``l * n_pages + p`` of a (n_layers * n_pages, page, K, hd) buffer.
+    """Flat head-major KV pools: layer ``l``'s page ``p`` is flat slot
+    ``l * n_pages + p`` of a (n_layers * n_pages, K, page, hd) buffer.
 
     ``kv_sharding``: optional ``NamedSharding`` for tensor-parallel
-    serving — the canonical TP layout shards axis 2 (``kv_heads``) on the
-    mesh's ``model`` axis (``P(None, None, "model", None)``), so each
+    serving — the canonical TP layout shards axis 1 (``kv_heads``) on the
+    mesh's ``model`` axis (``P(None, "model", None, None)``), so each
     device holds every page but only its head slice and paged attention
     needs no collective (softmax is head-local).  The page-id geometry is
     unchanged: block tables, the pager, and migration stay shard-agnostic.
     """
     hd = cfg.resolved_head_dim
-    shape = (cfg.n_layers * n_pages, page_size, cfg.n_kv_heads, hd)
+    shape = (cfg.n_layers * n_pages, cfg.n_kv_heads, page_size, hd)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if kv_sharding is not None:
         pools = {s: jax.device_put(p, kv_sharding)
                  for s, p in pools.items()}
     return pools
+
+
+def _append_kv(pool, page, off, new, active):
+    """Write one decode token's KV per row into a head-major pool.
+
+    page/off (B,) flat page slot and in-page offset; new (B, K, hd);
+    inactive rows leave the pool as it was.  One dynamic-update-slice
+    per (row, kv head) into a ``(slots * K * page, hd)`` view of the
+    pool, rather than a scatter or a 4-d slice: XLA's TPU backend lays a
+    scattered (or strided-slab) pool out with the written dims minor, so
+    it would relayout the whole pool at every layer around the
+    paged-attention kernel, which reads it row-major."""
+    n_flat, kh, page_size, hd = pool.shape
+    rows = pool.reshape(n_flat * kh * page_size, hd)
+    for b in range(new.shape[0]):
+        for k in range(kh):
+            at = ((page[b] * kh + k) * page_size + off[b], 0)  # XLA clamps
+            old = jax.lax.dynamic_slice(rows, at, (1, hd))
+            upd = new[b, k][None].astype(pool.dtype)
+            rows = jax.lax.dynamic_update_slice(
+                rows, jnp.where(active[b], upd, old), at)
+    return rows.reshape(pool.shape)
 
 
 def write_prefill(pools, layer_kv, tables, lens, page_size: int):
@@ -108,7 +132,7 @@ def write_prefill(pools, layer_kv, tables, lens, page_size: int):
 
     def write(pool, new):
         upd = new.reshape(l * b * s, kh, hd).astype(pool.dtype)
-        return pool.at[flat_page, flat_off].set(upd, mode="drop")
+        return pool.at[flat_page, :, flat_off].set(upd, mode="drop")
 
     return {"k": write(pools["k"], ks), "v": write(pools["v"], vs)}
 
@@ -147,7 +171,7 @@ def gather_kv_pages(pools, flat_idx):
     """Device-side compact gather of live KV pages.
 
     ``flat_idx`` (n,) int32 flat pool slots (see :func:`flat_page_indices`);
-    returns ``{"k": (n, page, K, hd), "v": ...}`` — the transfer buffer a
+    returns ``{"k": (n, K, page, hd), "v": ...}`` — the transfer buffer a
     migration snapshot ships, and the payload the MMU pager preserves on
     evict.  Pools are NOT donated (the source keeps serving until the
     move commits).  Retraces per distinct gather size — this is the cold
@@ -194,6 +218,37 @@ def prefill_paged(params, pools, tokens, lens, tables, rng, temperatures,
     rng, sub = jax.random.split(rng)
     first = sample_per_row(sub, logits, temperatures, top_k, top_p)
     return first, pools, rng
+
+
+def _attend_pages(q, kp, vp, tables, base, mask, *, cfg: ModelConfig):
+    """Exact attention of prefill queries over a dense gather of their
+    pages (ref-oracle style).
+
+    q (N, T, H, hd); kp/vp the flat head-major pools; tables (N, maxp)
+    per-layer page ids; ``base`` the layer's flat-slot offset; mask
+    (N, T, S) with S = maxp * page_size.  Returns (N, T, H, hd) float32;
+    a query with no visible key attends to nothing."""
+    n, t = q.shape[:2]
+    kh = cfg.n_kv_heads
+    g = cfg.n_heads // kh
+    maxp = tables.shape[1]
+    safe = (jnp.maximum(tables, 0) + base).reshape(-1)
+
+    def dense(pool):                                    # (N, K, S, hd)
+        x = jnp.take(pool, safe, axis=0)                # (N*maxp, K, pg, hd)
+        x = x.reshape(n, maxp, kh, *pool.shape[2:]).swapaxes(1, 2)
+        return x.reshape(n, kh, -1, pool.shape[-1]).astype(jnp.float32)
+
+    qf = q.reshape(n, t, kh, g, -1).astype(jnp.float32)
+    s = jnp.einsum("ntkgd,nksd->nkgts", qf, dense(kp)) * (
+        cfg.resolved_head_dim ** -0.5)
+    s = jnp.where(mask[:, None, None], s, attention.NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    att = jnp.einsum("nkgts,nksd->ntkgd", p, dense(vp))
+    any_ok = jnp.any(mask, axis=-1)                     # (N,T)
+    att = jnp.where(any_ok[:, :, None, None, None], att, 0.0)
+    return att.reshape(n, t, cfg.n_heads, -1)
 
 
 def _prefill_shared_impl(params, pools, tokens, q_lens, q_starts,
@@ -244,9 +299,6 @@ def _prefill_shared_impl(params, pools, tokens, q_lens, q_starts,
     maxp = tables.shape[1]
     n_flat = pools["k"].shape[0]
     n_pages = n_flat // cfg.n_layers
-    kh = cfg.n_kv_heads
-    g = cfg.n_heads // kh
-    scale = cfg.resolved_head_dim ** -0.5
     pos = q_starts[:, None] + jnp.arange(t)[None, :]        # (N,T) absolute
     qvalid = jnp.arange(t)[None, :] < q_lens[:, None]
     kv_lens = q_starts + q_lens                             # full prompt len
@@ -257,6 +309,7 @@ def _prefill_shared_impl(params, pools, tokens, q_lens, q_starts,
     kpos = jnp.arange(maxp * page_size)[None]               # (1,S)
     page_ok = jnp.repeat(tables >= 0, page_size, axis=1)    # (N,S)
     kv_ok = (kpos < kv_lens[:, None]) & page_ok             # (N,S)
+    mask = kv_ok[:, None, :] & (kpos[:, None, :] <= pos[:, :, None])
 
     x = layers.embed_lookup(params["embed"], tokens)        # (N,T,D)
 
@@ -273,27 +326,11 @@ def _prefill_shared_impl(params, pools, tokens, q_lens, q_starts,
         # masked-off writes (shared-prefix positions, padding, unmapped
         # pages) drop at the out-of-bounds slot
         drop_page = jnp.where(wvalid, base + ppage, n_flat)
-        kp = kp.at[drop_page, off].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[drop_page, off].set(v.astype(vp.dtype), mode="drop")
-        # gather the full paged KV (shared prefix + fresh suffix) and
-        # run exact causal attention against it, ref-oracle style
-        safe = jnp.maximum(tables, 0) + base
-        kg = jnp.take(kp, safe.reshape(-1), axis=0).reshape(
-            n, maxp * page_size, kh, -1)
-        vg = jnp.take(vp, safe.reshape(-1), axis=0).reshape(
-            n, maxp * page_size, kh, -1)
-        qf = q.reshape(n, t, kh, g, -1).astype(jnp.float32)
-        s = jnp.einsum("ntkgd,nskd->nkgts", qf,
-                       kg.astype(jnp.float32)) * scale
-        mask = kv_ok[:, None, :] & (kpos[:, None, :] <= pos[:, :, None])
-        s = jnp.where(mask[:, None, None], s, attention.NEG_INF)
-        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-        att = jnp.einsum("nkgts,nskd->ntkgd", p, vg.astype(jnp.float32))
-        any_ok = jnp.any(mask, axis=-1)                     # (N,T)
-        att = jnp.where(any_ok[:, :, None, None, None], att, 0.0)
-        att = att.reshape(n, t, cfg.n_heads, -1).astype(x.dtype)
-        o = attention.out_proj(lp["attn"], cfg, att)
+        kp = kp.at[drop_page, :, off].set(k.astype(kp.dtype), mode="drop")
+        vp = vp.at[drop_page, :, off].set(v.astype(vp.dtype), mode="drop")
+        # attend over the full paged KV (shared prefix + fresh suffix)
+        att = _attend_pages(q, kp, vp, tables, base, mask, cfg=cfg)
+        o = attention.out_proj(lp["attn"], cfg, att.astype(x.dtype))
         if psum_attn is not None:
             o = psum_attn(o)
         x = x + o
@@ -366,9 +403,6 @@ def _prefill_chunk_impl(params, pools, tokens, q_lens, q_starts, tables,
     maxp = tables.shape[1]
     n_flat = pools["k"].shape[0]
     n_pages = n_flat // cfg.n_layers
-    kh = cfg.n_kv_heads
-    g = cfg.n_heads // kh
-    scale = cfg.resolved_head_dim ** -0.5
     pos = q_starts[:, None] + jnp.arange(t)[None, :]        # (N,T) absolute
     qvalid = jnp.arange(t)[None, :] < q_lens[:, None]
     kv_lens = q_starts + q_lens                  # tokens in cache after us
@@ -379,6 +413,7 @@ def _prefill_chunk_impl(params, pools, tokens, q_lens, q_starts, tables,
     kpos = jnp.arange(maxp * page_size)[None]               # (1,S)
     page_ok = jnp.repeat(tables >= 0, page_size, axis=1)    # (N,S)
     kv_ok = (kpos < kv_lens[:, None]) & page_ok             # (N,S)
+    mask = kv_ok[:, None, :] & (kpos[:, None, :] <= pos[:, :, None])
 
     x = layers.embed_lookup(params["embed"], tokens)        # (N,T,D)
 
@@ -392,25 +427,10 @@ def _prefill_chunk_impl(params, pools, tokens, q_lens, q_starts, tables,
             q = layers.apply_rope(q, pos, cfg.rope_theta)
             k = layers.apply_rope(k, pos, cfg.rope_theta)
         drop_page = jnp.where(wvalid, base + ppage, n_flat)
-        kp = kp.at[drop_page, off].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[drop_page, off].set(v.astype(vp.dtype), mode="drop")
-        safe = jnp.maximum(tables, 0) + base
-        kg = jnp.take(kp, safe.reshape(-1), axis=0).reshape(
-            n, maxp * page_size, kh, -1)
-        vg = jnp.take(vp, safe.reshape(-1), axis=0).reshape(
-            n, maxp * page_size, kh, -1)
-        qf = q.reshape(n, t, kh, g, -1).astype(jnp.float32)
-        s = jnp.einsum("ntkgd,nskd->nkgts", qf,
-                       kg.astype(jnp.float32)) * scale
-        mask = kv_ok[:, None, :] & (kpos[:, None, :] <= pos[:, :, None])
-        s = jnp.where(mask[:, None, None], s, attention.NEG_INF)
-        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-        att = jnp.einsum("nkgts,nskd->ntkgd", p, vg.astype(jnp.float32))
-        any_ok = jnp.any(mask, axis=-1)                     # (N,T)
-        att = jnp.where(any_ok[:, :, None, None, None], att, 0.0)
-        att = att.reshape(n, t, cfg.n_heads, -1).astype(x.dtype)
-        o = attention.out_proj(lp["attn"], cfg, att)
+        kp = kp.at[drop_page, :, off].set(k.astype(kp.dtype), mode="drop")
+        vp = vp.at[drop_page, :, off].set(v.astype(vp.dtype), mode="drop")
+        att = _attend_pages(q, kp, vp, tables, base, mask, cfg=cfg)
+        o = attention.out_proj(lp["attn"], cfg, att.astype(x.dtype))
         if psum_attn is not None:
             o = psum_attn(o)
         x = x + o
@@ -439,10 +459,68 @@ def prefill_chunk_paged(params, pools, tokens, q_lens, q_starts, tables,
                                tables, cfg=cfg, page_size=page_size)
 
 
+def _decode_logits_impl(params, pools, tables, lens, last_tokens, *,
+                        cfg: ModelConfig, page_size: int,
+                        use_pallas: Optional[bool] = None,
+                        pages_per_block: Optional[int] = None,
+                        psum_attn=None, psum_mlp=None):
+    """The model half of a decode step: append each live row's KV at
+    position ``lens`` and return ``(logits (B, vocab) float32,
+    new_pools)``.  ``use_pallas=None`` lets the platform choose the
+    attention kernel (see :mod:`repro.kernels`)."""
+    maxp = tables.shape[1]
+    n_flat = pools["k"].shape[0]
+    n_pages = n_flat // cfg.n_layers
+    x = layers.embed_lookup(params["embed"], last_tokens[:, None])
+    pos = lens                                        # 0-based new position
+    vpage = jnp.minimum(pos // page_size, maxp - 1)
+    off = pos % page_size
+    ppage = jnp.take_along_axis(tables, vpage[:, None], axis=1)[:, 0]
+    active = ppage >= 0
+    kv_lens = jnp.where(active, lens + 1, 0)
+
+    def body(carry, inp):
+        x, kp, vp = carry
+        li, lp = inp
+        base = li * n_pages
+        h = layers.norm_apply(lp["norm1"], x, cfg.norm_eps)
+        q, k, v = attention.qkv_proj(lp["attn"], cfg, h)
+        if cfg.pos_embed == "rope":
+            q = layers.apply_rope(q, pos[:, None], cfg.rope_theta)
+            k = layers.apply_rope(k, pos[:, None], cfg.rope_theta)
+        knew = k[:, 0].astype(kp.dtype)               # (B,K,hd)
+        vnew = v[:, 0].astype(vp.dtype)
+        kp = _append_kv(kp, base + ppage, off, knew, active)
+        vp = _append_kv(vp, base + ppage, off, vnew, active)
+        ltab = jnp.where(tables >= 0, tables + base, -1)
+        att = paged_decode(q[:, 0], kp, vp, ltab, kv_lens,
+                           use_pallas=use_pallas,
+                           pages_per_block=pages_per_block)
+        o = attention.out_proj(lp["attn"], cfg, att[:, None])
+        if psum_attn is not None:
+            o = psum_attn(o)
+        x = x + o
+        h = layers.norm_apply(lp["norm2"], x, cfg.norm_eps)
+        if _is_moe_layer(cfg):
+            out, _ = moe.moe_apply(lp["ffn"], cfg, h)
+        else:
+            out = mlp.mlp_apply(lp["ffn"], cfg, h)
+        if psum_mlp is not None:
+            out = psum_mlp(out)
+        return (x + out, kp, vp), None
+
+    (x, kpool, vpool), _ = jax.lax.scan(
+        body, (x, pools["k"], pools["v"]),
+        (jnp.arange(cfg.n_layers), params["layers"]))
+    x = layers.norm_apply(params["final_norm"], x, cfg.norm_eps)
+    logits = lm_logits(params, cfg, x)[:, 0][..., :cfg.vocab_size]
+    return logits, {"k": kpool, "v": vpool}
+
+
 def _decode_step_impl(params, pools, tables, lens, last_tokens, rng,
                       temperatures, top_k=None, top_p=None, seq_ids=None,
                       *, cfg: ModelConfig, page_size: int,
-                      use_pallas: bool = False,
+                      use_pallas: Optional[bool] = None,
                       pages_per_block: Optional[int] = None,
                       psum_attn=None, psum_mlp=None):
     """One fused decode step for the whole running batch.
@@ -467,54 +545,11 @@ def _decode_step_impl(params, pools, tables, lens, last_tokens, rng,
     weights and KV pools, and the hooks all-reduce the out-proj and FFN
     partial sums over the ``model`` axis.
     """
-    maxp = tables.shape[1]
-    n_flat = pools["k"].shape[0]
-    n_pages = n_flat // cfg.n_layers
-    x = layers.embed_lookup(params["embed"], last_tokens[:, None])
-    pos = lens                                        # 0-based new position
-    vpage = jnp.minimum(pos // page_size, maxp - 1)
-    off = pos % page_size
-    ppage = jnp.take_along_axis(tables, vpage[:, None], axis=1)[:, 0]
-    active = ppage >= 0
-    kv_lens = jnp.where(active, lens + 1, 0)
-
-    def body(carry, inp):
-        x, kp, vp = carry
-        li, lp = inp
-        base = li * n_pages
-        h = layers.norm_apply(lp["norm1"], x, cfg.norm_eps)
-        q, k, v = attention.qkv_proj(lp["attn"], cfg, h)
-        if cfg.pos_embed == "rope":
-            q = layers.apply_rope(q, pos[:, None], cfg.rope_theta)
-            k = layers.apply_rope(k, pos[:, None], cfg.rope_theta)
-        knew = k[:, 0].astype(kp.dtype)               # (B,K,hd)
-        vnew = v[:, 0].astype(vp.dtype)
-        # inactive rows write one past the pool end: dropped by "drop"
-        drop_page = jnp.where(active, base + ppage, n_flat)
-        kp = kp.at[drop_page, off].set(knew, mode="drop")
-        vp = vp.at[drop_page, off].set(vnew, mode="drop")
-        ltab = jnp.where(tables >= 0, tables + base, -1)
-        att = paged_decode(q[:, 0], kp, vp, ltab, kv_lens,
-                           use_pallas=use_pallas,
-                           pages_per_block=pages_per_block)
-        o = attention.out_proj(lp["attn"], cfg, att[:, None])
-        if psum_attn is not None:
-            o = psum_attn(o)
-        x = x + o
-        h = layers.norm_apply(lp["norm2"], x, cfg.norm_eps)
-        if _is_moe_layer(cfg):
-            out, _ = moe.moe_apply(lp["ffn"], cfg, h)
-        else:
-            out = mlp.mlp_apply(lp["ffn"], cfg, h)
-        if psum_mlp is not None:
-            out = psum_mlp(out)
-        return (x + out, kp, vp), None
-
-    (x, kpool, vpool), _ = jax.lax.scan(
-        body, (x, pools["k"], pools["v"]),
-        (jnp.arange(cfg.n_layers), params["layers"]))
-    x = layers.norm_apply(params["final_norm"], x, cfg.norm_eps)
-    logits = lm_logits(params, cfg, x)[:, 0][..., :cfg.vocab_size]
+    logits, pools = _decode_logits_impl(
+        params, pools, tables, lens, last_tokens, cfg=cfg,
+        page_size=page_size, use_pallas=use_pallas,
+        pages_per_block=pages_per_block, psum_attn=psum_attn,
+        psum_mlp=psum_mlp)
     if seq_ids is None:
         rng, sub = jax.random.split(rng)
     else:
@@ -530,7 +565,7 @@ def _decode_step_impl(params, pools, tables, lens, last_tokens, rng,
     # self-reactivates once its next page is mapped (slot transitions
     # reset the counters host-side).
     new_lens = lens + 1
-    return next_tokens, {"k": kpool, "v": vpool}, new_lens, rng
+    return next_tokens, pools, new_lens, rng
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "page_size",
@@ -540,7 +575,7 @@ def _decode_step_impl(params, pools, tables, lens, last_tokens, rng,
 def decode_step_paged(params, pools, tables, lens, last_tokens, rng,
                       temperatures, top_k=None, top_p=None, seq_ids=None,
                       *, cfg: ModelConfig, page_size: int,
-                      use_pallas: bool = False,
+                      use_pallas: Optional[bool] = None,
                       pages_per_block: Optional[int] = None):
     """Jitted single-device entry point over :func:`_decode_step_impl`
     (see its docstring for the full contract).  The tensor-parallel twin
@@ -550,3 +585,20 @@ def decode_step_paged(params, pools, tables, lens, last_tokens, rng,
         params, pools, tables, lens, last_tokens, rng, temperatures,
         top_k, top_p, seq_ids, cfg=cfg, page_size=page_size,
         use_pallas=use_pallas, pages_per_block=pages_per_block)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "page_size",
+                                             "use_pallas",
+                                             "pages_per_block"))
+def decode_logits_paged(params, pools, tables, lens, last_tokens, *,
+                        cfg: ModelConfig, page_size: int,
+                        use_pallas: Optional[bool] = None,
+                        pages_per_block: Optional[int] = None):
+    """Logit-level twin of :func:`decode_step_paged` for parity checks:
+    same model half, no sampling, nothing donated.  Returns
+    ``(logits (B, vocab) float32, new_pools)``, so two kernel paths can
+    be compared on one pool state."""
+    return _decode_logits_impl(
+        params, pools, tables, lens, last_tokens, cfg=cfg,
+        page_size=page_size, use_pallas=use_pallas,
+        pages_per_block=pages_per_block)

@@ -12,7 +12,7 @@ partitioned:
     row-sharded; SwiGLU ``w_gate/w_up`` column-sharded on ``d_ff``,
     ``w_down`` row-sharded.  Embeddings, norms, lm_head and MoE experts
     stay replicated.
-  * **KV pools** shard axis 2 (``kv_heads``) on ``model``: each device
+  * **KV pools** shard axis 1 (``kv_heads``) on ``model``: each device
     holds EVERY page but only its head slice, so paged attention is
     collective-free (per-head softmax is device-local) and the page-id
     geometry — block tables, pager, migration wire format — is untouched.
@@ -43,10 +43,9 @@ from typing import Dict, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.configs.base import ModelConfig
 from repro.core.services.collectives import CollectiveService
+from repro.kernels import resolve_use_pallas
 from repro.models.sharding import MeshRules
 from repro.serve import paged_model
 
@@ -79,7 +78,7 @@ class TPContext:
     """
 
     def __init__(self, cfg: ModelConfig, mesh: Mesh, params, *,
-                 page_size: int, use_pallas: bool = False,
+                 page_size: int, use_pallas: Optional[bool] = None,
                  pages_per_block: Optional[int] = None,
                  collectives: Optional[CollectiveService] = None):
         self.cfg = cfg
@@ -104,7 +103,7 @@ class TPContext:
         else:
             self.local_cfg = cfg
         self.replicated = NamedSharding(mesh, P())
-        self.kv_spec = (P(None, None, self.axis, None) if self.shard_heads
+        self.kv_spec = (P(None, self.axis, None, None) if self.shard_heads
                         else P())
         self.kv_sharding = NamedSharding(mesh, self.kv_spec)
         self._pspecs = self._param_specs(params)
@@ -114,7 +113,8 @@ class TPContext:
                                  is_leaf=lambda x: isinstance(x, P)))
         self._psum_attn = self._reduce if self.shard_heads else None
         self._psum_mlp = self._reduce if self.shard_mlp else None
-        self.decode_step = self._build_decode(page_size, use_pallas,
+        self.use_pallas = resolve_use_pallas(use_pallas)
+        self.decode_step = self._build_decode(page_size, self.use_pallas,
                                               pages_per_block)
         self.prefill_shared = self._build_prefill_shared(page_size)
         self.prefill_chunk = self._build_prefill_chunk(page_size)
@@ -160,13 +160,13 @@ class TPContext:
             return impl(params, pools, tables, lens, last, rng, temps, tk,
                         tp_, sids)
 
-        sm = _shard_map(
+        sm = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._pspecs, {"k": self.kv_spec, "v": self.kv_spec},
                       P(), P(), P(), P(), P(), P(), P(), P()),
             out_specs=(P(), {"k": self.kv_spec, "v": self.kv_spec},
                        P(), P()),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(sm, donate_argnums=(1, 3, 4, 5))
 
     def _build_prefill_shared(self, page_size):
@@ -181,12 +181,12 @@ class TPContext:
             return impl(params, pools, tokens, q_lens, q_starts,
                         write_from, tables, rng, temps, tk, tp_, sids)
 
-        sm = _shard_map(
+        sm = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._pspecs, {"k": self.kv_spec, "v": self.kv_spec},
                       P(), P(), P(), P(), P(), P(), P(), P(), P(), P()),
             out_specs=(P(), {"k": self.kv_spec, "v": self.kv_spec}, P()),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(sm, donate_argnums=(1, 7))
 
     def _build_prefill_chunk(self, page_size):
@@ -199,12 +199,12 @@ class TPContext:
             paged_model._count_trace("prefill_chunk_paged_tp")
             return impl(params, pools, tokens, q_lens, q_starts, tables)
 
-        sm = _shard_map(
+        sm = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._pspecs, {"k": self.kv_spec, "v": self.kv_spec},
                       P(), P(), P(), P()),
             out_specs={"k": self.kv_spec, "v": self.kv_spec},
-            check_rep=False)
+            check_vma=False)
         return jax.jit(sm, donate_argnums=(1,))
 
     # ------------------------------------------------------------- extras ----

@@ -202,10 +202,9 @@ def analyze(compiled, *, chips: int, model_flops: float = 0.0,
     that execute as single Pallas kernels on the TPU target; the caller
     adds the kernel boundary traffic via ``extra_bytes_per_device``
     (see :func:`fused_boundary_bytes`)."""
-    from repro.compat import normalize_cost_analysis
     from repro.telemetry import hlo_cost
 
-    ca = normalize_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     totals = hlo_cost.analyze_text(compiled.as_text(),
                                    discount_scope=discount_scope)
     coll = CollectiveStats(

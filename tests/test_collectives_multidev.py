@@ -13,12 +13,11 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
 
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                            axis_types=compat.auto_axis_types(3))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
 
     # ---- hierarchical all-reduce == flat psum -----------------------------
     from repro.core.services.collectives import CollectiveService, CollectiveConfig
@@ -31,10 +30,10 @@ SCRIPT = textwrap.dedent("""
     def hier(v):
         return svc.all_reduce(v, mesh)
 
-    f = shard_map(flat, mesh=mesh, in_specs=P(("pod", "data"), None),
-                  out_specs=P(None, None), check_rep=False)
-    h = shard_map(hier, mesh=mesh, in_specs=P(("pod", "data"), None),
-                  out_specs=P(None, None), check_rep=False)
+    f = jax.shard_map(flat, mesh=mesh, in_specs=P(("pod", "data"), None),
+                      out_specs=P(None, None), check_vma=False)
+    h = jax.shard_map(hier, mesh=mesh, in_specs=P(("pod", "data"), None),
+                      out_specs=P(None, None), check_vma=False)
     a, b = np.asarray(f(x)), np.asarray(h(x))
     assert np.allclose(a, b, atol=1e-5), (a, b)
 

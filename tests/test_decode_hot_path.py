@@ -10,8 +10,9 @@ from repro.configs import get_config
 from repro.core.services.mmu import MMU, MMUConfig
 from repro.models import transformer as T
 from repro.serve.engine import ServingEngine
-from repro.serve.paged_model import (TRACE_COUNTS, decode_step_paged,
-                                     make_pools, write_prefill)
+from repro.serve.paged_model import (TRACE_COUNTS, decode_logits_paged,
+                                     decode_step_paged, make_pools,
+                                     write_prefill)
 from repro.serve.sampler import sample_per_row
 
 
@@ -121,6 +122,52 @@ def test_pallas_engine_matches_ref_engine_with_slot_churn(served):
     assert ref == pal
 
 
+def test_decode_logits_kernel_matches_ref_and_step_appends_in_place(served):
+    """``decode_logits_paged``: the Pallas kernel's logits match the XLA
+    reference on one scattered pool state, each live row's KV lands at
+    (its page, its offset) of the head-major pool and nowhere else, an
+    inactive row writes nothing, and the sampling step's greedy tokens
+    and pools agree with the logits program."""
+    cfg, params = served
+    page, n_pages = 16, 32
+    shape = make_pools(cfg, n_pages, page)["k"].shape
+    keys = jax.random.split(jax.random.PRNGKey(2))
+    pools = {s: jax.random.normal(k, shape) for s, k in zip("kv", keys)}
+    tables = jnp.asarray([[5, 9, -1, -1], [2, -1, -1, -1],
+                          [-1, -1, -1, -1]], jnp.int32)
+    lens = jnp.asarray([20, 15, 0], jnp.int32)    # row 2 is an empty slot
+    last = jnp.asarray([7, 8, 0], jnp.int32)
+    out = {p: decode_logits_paged(
+               params, pools, tables, lens, last, cfg=cfg, page_size=page,
+               use_pallas=p) for p in (True, False)}
+    lp, lr = (np.asarray(out[p][0]) for p in (True, False))
+    assert lp.shape == (3, cfg.vocab_size) and np.isfinite(lp).all()
+    np.testing.assert_allclose(lp, lr, rtol=0,
+                               atol=1e-5 * np.abs(lr).max())
+    for s in "kv":
+        # layer 0's attention rounds differently per path, so deeper
+        # layers append slightly different KV
+        np.testing.assert_allclose(out[True][1][s], out[False][1][s],
+                                   rtol=0, atol=1e-5)
+        new = np.asarray(out[False][1][s])
+        old = np.asarray(pools[s])
+        written = np.zeros(shape[:1] + shape[2:3], bool)   # (slot, offset)
+        for layer in range(cfg.n_layers):
+            written[layer * n_pages + 9, 20 % page] = True
+            written[layer * n_pages + 2, 15] = True
+        changed = (new != old).any(axis=(1, 3))
+        np.testing.assert_array_equal(changed, written)
+
+    toks, step_pools, _, _ = decode_step_paged(
+        params, jax.tree.map(jnp.copy, pools), tables, lens, last,
+        jax.random.PRNGKey(0), jnp.zeros((3,), jnp.float32), cfg=cfg,
+        page_size=page, use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(toks)[:2], lr[:2].argmax(-1))
+    for s in "kv":
+        np.testing.assert_allclose(step_pools[s], out[False][1][s],
+                                   rtol=0, atol=1e-5)
+
+
 # ----------------------------------------------- incremental tables ----
 def test_device_block_table_is_incremental():
     mmu = MMU(MMUConfig(page_size=4, n_pages=64))
@@ -171,15 +218,17 @@ def test_write_prefill_drops_invalid_writes(served):
     kh = cfg.n_kv_heads
     L = cfg.n_layers
     sentinel = 7.5
-    pools = {k: jnp.full((L * n_pages, page, kh, hd), sentinel)
+    pools = {k: jnp.full((L * n_pages, kh, page, hd), sentinel)
              for k in ("k", "v")}
     ks = jax.random.normal(jax.random.PRNGKey(0), (L, b, s, kh, hd))
     vs = ks + 1.0
     tables = jnp.asarray([[2, 5, 1, -1], [6, -1, -1, -1]], jnp.int32)
     lens = jnp.asarray([10, 3], jnp.int32)
     out = write_prefill(pools, (ks, vs), tables, lens, page)
-    # flat layout: layer l's page p lives at slot l*n_pages + p
-    outk = np.asarray(out["k"]).reshape(L, n_pages, page, kh, hd)
+    # flat head-major layout: layer l's page p lives at slot l*n_pages + p;
+    # viewed token-major below so positions index axis 2
+    outk = np.asarray(out["k"]).reshape(L, n_pages, kh, page, hd)
+    outk = outk.swapaxes(2, 3)
     # mapped positions hold the prefill KV
     np.testing.assert_allclose(outk[:, 2], np.asarray(ks[:, 0, 0:4]))
     np.testing.assert_allclose(outk[:, 5], np.asarray(ks[:, 0, 4:8]))

@@ -84,8 +84,8 @@ def test_paged_attention_matches_ref(case, ppb):
     b, h, kh, d, page, maxp, npages = case
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (npages, page, kh, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (npages, page, kh, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (npages, kh, page, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (npages, kh, page, d), jnp.float32)
     lens = np.minimum(np.arange(1, b + 1) * (page + 7), page * maxp)
     tables = _tables(b, page, maxp, npages, lens)
     out = paged_attention(q, kp, vp, jnp.asarray(tables),
@@ -103,8 +103,8 @@ def test_paged_attention_ragged_occupancy_page_groups():
     b, h, kh, d, page, maxp, npages = 3, 4, 2, 64, 16, 7, 32
     ks = jax.random.split(jax.random.PRNGKey(9), 3)
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (npages, page, kh, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (npages, page, kh, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (npages, kh, page, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (npages, kh, page, d), jnp.float32)
     lens = jnp.asarray([0, 32, 100], jnp.int32)
     tables = np.full((b, maxp), -1, np.int32)
     tables[1, :2] = [5, 9]
@@ -127,16 +127,16 @@ def test_paged_matches_dense_attention():
     kd = jax.random.normal(ks[1], (b, maxp * page, kh, d), jnp.float32)
     vd = jax.random.normal(ks[2], (b, maxp * page, kh, d), jnp.float32)
     # scatter the dense kv into pages per the tables
-    kp = jnp.zeros((npages, page, kh, d), jnp.float32)
-    vp = jnp.zeros((npages, page, kh, d), jnp.float32)
+    kp = jnp.zeros((npages, kh, page, d), jnp.float32)
+    vp = jnp.zeros((npages, kh, page, d), jnp.float32)
     for i in range(b):
         for vp_i in range(maxp):
             pp = tables[i, vp_i]
             if pp < 0:
                 continue
             sl = slice(vp_i * page, (vp_i + 1) * page)
-            kp = kp.at[pp].set(kd[i, sl])
-            vp = vp.at[pp].set(vd[i, sl])
+            kp = kp.at[pp].set(kd[i, sl].swapaxes(0, 1))
+            vp = vp.at[pp].set(vd[i, sl].swapaxes(0, 1))
         # dense ref per row (pages are per-row exclusive in this test)
         q = jax.random.normal(ks[0], (1, h, d), jnp.float32)
         out = paged_attention(q, kp, vp, jnp.asarray(tables[i:i+1]),

@@ -134,7 +134,7 @@ def test_tp2_token_parity_under_churn_and_eviction():
             and tp2.tp.shard_mlp
         # local shard of the KV pool holds kv_heads // 2 heads
         local = tp2.pools["k"].addressable_shards[0].data.shape
-        assert local[2] == cfg.n_kv_heads // 2, local
+        assert local[1] == cfg.n_kv_heads // 2, local
         # 5 requests through 2 slots: admission churn + queueing; greedy,
         # sampled, and top-k/top-p filtered rows
         reqs = [(list(range(3, 9)), 0.0, 0, 1.0),

@@ -290,7 +290,7 @@ def test_snapshot_version_and_corruption_rejected(served):
     h2, a2 = decode_snapshot(blob)
     assert h2["geometry"] == eng.geometry()
     # version-mismatched state container
-    tampered = blob.replace(b'"state_version": 2', b'"state_version": 9', 1)
+    tampered = blob.replace(b'"state_version": 3', b'"state_version": 9', 1)
     with pytest.raises(BitstreamError, match="state version"):
         decode_snapshot(tampered)
     # wrong kind refuses before any state is touched

@@ -55,11 +55,11 @@ DISAGG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.serve.disaggregated import make_handoff_fn, handoff_wire_bytes
 
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                            axis_types=compat.auto_axis_types(3))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     handoff, qp = make_handoff_fn(mesh)
     # dim0 pod-sharded: rows 0-1 = prefill pod KV, rows 2-3 = decode pool
     cache = {"k": jnp.arange(4 * 6, dtype=jnp.float32).reshape(4, 6),

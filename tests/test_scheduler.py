@@ -1,11 +1,8 @@
 """Multi-tenant shell scheduler: weighted-credit QoS, SG coalescing,
-per-tenant accounting, and the JAX cost_analysis compat helper."""
-import jax
-import jax.numpy as jnp
+per-tenant accounting."""
 import numpy as np
 import pytest
 
-from repro.compat import normalize_cost_analysis
 from repro.core import Alloc, AppArtifact, Oper, SgEntry, Shell, ShellConfig
 from repro.core.credits import (Link, WeightedRRArbiter, jains_index,
                                 weighted_jains_index)
@@ -225,18 +222,3 @@ def test_submit_with_unknown_tenant_autoregisters():
                                    wait=True, timeout=30.0)
     assert ev.is_set()
     assert shell.scheduler.stats()["tenants"]["newbie"]["weight"] == 1.0
-
-
-# ======================================== cost_analysis compat regression ===
-def test_cost_analysis_normalization_helper():
-    assert normalize_cost_analysis(None) == {}
-    assert normalize_cost_analysis([]) == {}
-    assert normalize_cost_analysis({"flops": 2.0}) == {"flops": 2.0}
-    assert normalize_cost_analysis([{"flops": 2.0}]) == {"flops": 2.0}
-    assert normalize_cost_analysis([None, {"a": 1.0}]) == {"a": 1.0}
-    # whatever shape the installed JAX returns must flatten to a dict
-    c = (jax.jit(lambda a: a * 2)
-         .lower(jax.ShapeDtypeStruct((8,), jnp.float32)).compile())
-    ca = normalize_cost_analysis(c.cost_analysis())
-    assert isinstance(ca, dict)
-    assert float(ca.get("flops", 0.0)) >= 0.0
