@@ -37,6 +37,8 @@ from dataclasses import asdict, dataclass, field
 from enum import Enum
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.faults import FaultKind, InjectedFault
 from repro.core.interfaces import Completion, Oper, SgEntry
 from repro.core.scheduler import SHARED_LANE_SLOT_BASE
@@ -233,38 +235,41 @@ class Port:
         replayed (FIFO) on ``resume()`` — callers just see a future that
         resolves after the swap.
         """
-        fut = PortFuture(inv)
-        slot, default_tenant = self._fault_ctx()
-        tenant = inv.tenant or default_tenant
-        health = self._health()
-        if health is not None and health.is_quarantined(tenant):
-            # graceful degradation: a repeatedly-faulting tenant is
-            # rejected FAST with a typed error — bystanders keep flowing
-            health.record_rejection(tenant)
-            raise PortError(
-                f"tenant {tenant!r} is quarantined on port {self.name!r} "
-                "(repeated faults within the quarantine window); "
-                "Shell.health.unquarantine() to lift",
-                kind=FaultKind.QUARANTINED, slot=slot, tenant=tenant,
-                retryable=False)
-        with self._lock:
-            if self._closed:
+        with TraceAnnotation("repro.port.submit",
+                             tag=inv.meta.get("tag", inv.kind),
+                             nbytes=inv.nbytes):
+            fut = PortFuture(inv)
+            slot, default_tenant = self._fault_ctx()
+            tenant = inv.tenant or default_tenant
+            health = self._health()
+            if health is not None and health.is_quarantined(tenant):
+                # graceful degradation: a repeatedly-faulting tenant is
+                # rejected FAST with a typed error — bystanders keep flowing
+                health.record_rejection(tenant)
                 raise PortError(
-                    f"port {self.name!r} is closed (its slot/service was "
-                    "torn down, e.g. by cold_restart); re-attach through "
-                    "Shell.attach() for a live port")
-            if inv.ticket < 0:
-                inv.ticket = next(self._tickets)
-            if inv.t_accept == 0.0:
-                inv.t_accept = time.perf_counter()
-            self.submitted += 1
-            if self._state is not PortState.ACTIVE:
-                self._held.append((inv, fut))
-                self.held_peak = max(self.held_peak, len(self._held))
-                return fut
-            self._inflight[inv.ticket] = fut
-        self._safe_dispatch(inv, fut)
-        return fut
+                    f"tenant {tenant!r} is quarantined on port {self.name!r} "
+                    "(repeated faults within the quarantine window); "
+                    "Shell.health.unquarantine() to lift",
+                    kind=FaultKind.QUARANTINED, slot=slot, tenant=tenant,
+                    retryable=False)
+            with self._lock:
+                if self._closed:
+                    raise PortError(
+                        f"port {self.name!r} is closed (its slot/service was "
+                        "torn down, e.g. by cold_restart); re-attach through "
+                        "Shell.attach() for a live port")
+                if inv.ticket < 0:
+                    inv.ticket = next(self._tickets)
+                if inv.t_accept == 0.0:
+                    inv.t_accept = time.perf_counter()
+                self.submitted += 1
+                if self._state is not PortState.ACTIVE:
+                    self._held.append((inv, fut))
+                    self.held_peak = max(self.held_peak, len(self._held))
+                    return fut
+                self._inflight[inv.ticket] = fut
+            self._safe_dispatch(inv, fut)
+            return fut
 
     def call(self, inv: Invocation,
              timeout: Optional[float] = None) -> Completion:

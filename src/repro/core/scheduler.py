@@ -48,6 +48,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Set, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core import credits as C
 from repro.core.faults import FaultKind
 from repro.core.interfaces import Completion, SgEntry
@@ -226,7 +228,9 @@ class _ExecutorLane:
             self._hold_chain.append(task)
         t0 = time.perf_counter()
         try:
-            self.sched._execute_batch(task.batch, task.credit_cost)
+            with TraceAnnotation("repro.sched.execute",
+                                 nbytes=task.batch.nbytes):
+                self.sched._execute_batch(task.batch, task.credit_cost)
         finally:
             self.busy_s += time.perf_counter() - t0
             self.executed += 1
@@ -643,24 +647,40 @@ class ShellScheduler:
                     return
                 intake = list(self._intake)
                 self._intake.clear()
-            self._ingest(intake)
-            # issue credit-gated batches, drive the arbiter, repeat: every
-            # completed batch returns credits that may unblock more work.
-            while True:
-                issued = self._issue_ready()
-                self.arbiter.drain()
-                if not issued and not self.arbiter.pending():
-                    with self._lock:
-                        if self._intake or self._paused or self._stop:
-                            break
-                        if not self._has_ready():
-                            self._idle_cv.notify_all()
-                            break
-                        # ready work exists but is credit-blocked with an
-                        # idle arbiter: credits are held by batches still
-                        # executing on lanes.  Wait for a release
-                        # (CreditAccount.on_release notifies _work_cv).
-                        self._work_cv.wait(timeout=0.05)
+            with TraceAnnotation("repro.sched.issue", intake=len(intake)):
+                self._ingest(intake)
+                self._issue_all()
+            while self._wait_for_credits():
+                with TraceAnnotation("repro.sched.issue", intake=0):
+                    self._issue_all()
+
+    def _issue_all(self) -> None:
+        """Issue credit-gated batches and drive the arbiter until neither
+        has more to do: every completed batch returns credits that may
+        unblock more work."""
+        while True:
+            issued = self._issue_ready()
+            self.arbiter.drain()
+            if not issued and not self.arbiter.pending():
+                return
+
+    def _wait_for_credits(self) -> bool:
+        """After an issue pass: wait for a credit release and return True
+        when ready work is credit-blocked with an idle arbiter; return
+        False when new intake, a pause or a stop is for the outer loop,
+        or nothing is ready (waking ``drain()`` waiters)."""
+        with self._lock:
+            if self._intake or self._paused or self._stop:
+                return False
+            if not self._has_ready():
+                self._idle_cv.notify_all()
+                return False
+            # ready work exists but is credit-blocked with an idle
+            # arbiter: credits are held by batches still executing on
+            # lanes.  Wait for a release (CreditAccount.on_release
+            # notifies _work_cv).
+            self._work_cv.wait(timeout=0.05)
+            return True
 
     def _has_ready(self) -> bool:
         return any(self._pend.get(k) for k in self._pend_order)

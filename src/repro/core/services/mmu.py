@@ -986,35 +986,37 @@ class DeviceBlockTable:
 
     def device_view(self):
         """(n_slots, max_pages) int32 device array, incrementally synced."""
-        for i, sid in enumerate(self._seq):
-            if sid < 0:
-                continue
-            v = self.mmu.seq_map_version(sid)
-            if v != self._ver[i]:
-                self._host[i] = self.mmu.block_table(
-                    [sid], self.max_pages)[0]
-                self._ver[i] = v
-                self._stale.add(i)
-        if self._dev is None:
-            self._dev = (jax.device_put(self._host, self.sharding)
-                         if self.sharding is not None
-                         else jnp.asarray(self._host))
-            self.row_uploads += self.n_slots
-            self.last_updated_rows = list(range(self.n_slots))
-            self._stale.clear()
-        elif self._stale:
-            rows = sorted(self._stale)
-            self._dev = self._dev.at[jnp.asarray(rows, jnp.int32)].set(
-                jnp.asarray(self._host[rows]))
-            if self.sharding is not None:
-                # keep the mirror pinned replicated across the mesh (the
-                # scatter above follows the committed input, but be
-                # explicit: the TP decode jit keys on this sharding)
-                self._dev = jax.device_put(self._dev, self.sharding)
-            self.row_uploads += len(rows)
-            self.last_updated_rows = rows
-            self._stale.clear()
-        else:
-            self.hits += 1
-            self.last_updated_rows = []
+        with jax.profiler.TraceAnnotation("repro.mmu.block_table") as span:
+            for i, sid in enumerate(self._seq):
+                if sid < 0:
+                    continue
+                v = self.mmu.seq_map_version(sid)
+                if v != self._ver[i]:
+                    self._host[i] = self.mmu.block_table(
+                        [sid], self.max_pages)[0]
+                    self._ver[i] = v
+                    self._stale.add(i)
+            if self._dev is None:
+                self._dev = (jax.device_put(self._host, self.sharding)
+                             if self.sharding is not None
+                             else jnp.asarray(self._host))
+                self.row_uploads += self.n_slots
+                self.last_updated_rows = list(range(self.n_slots))
+                self._stale.clear()
+            elif self._stale:
+                rows = sorted(self._stale)
+                self._dev = self._dev.at[jnp.asarray(rows, jnp.int32)].set(
+                    jnp.asarray(self._host[rows]))
+                if self.sharding is not None:
+                    # keep the mirror pinned replicated across the mesh (the
+                    # scatter above follows the committed input, but be
+                    # explicit: the TP decode jit keys on this sharding)
+                    self._dev = jax.device_put(self._dev, self.sharding)
+                self.row_uploads += len(rows)
+                self.last_updated_rows = rows
+                self._stale.clear()
+            else:
+                self.hits += 1
+                self.last_updated_rows = []
+            span.set_metadata(rows=len(self.last_updated_rows))
         return self._dev

@@ -58,6 +58,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.faults import FaultKind
@@ -316,8 +317,9 @@ class ServingEngine:
         a = self._ewma_alpha
         return (1 - a) * prev + a * min(sample, 10.0 * prev)
 
-    def _admit(self) -> None:
-        """Admit queued requests into free slots under the page budget.
+    def _admit(self) -> int:
+        """Admit queued requests into free slots under the page budget;
+        returns how many were admitted.
 
         The queue head no longer blocks everything behind it: when a
         request does not fit the remaining page credits, admission scans
@@ -327,10 +329,10 @@ class ServingEngine:
         reordered, only independent tenants leapfrog a stuck head.
         """
         if not self.queue:
-            return
+            return 0
         free = [i for i in range(self.max_batch) if self.slots[i] is None]
         if not free:
-            return
+            return 0
         oneshot, taken, blocked = [], set(), set()
         qlist = list(self.queue)
         for qi, req in enumerate(qlist):
@@ -342,25 +344,29 @@ class ServingEngine:
                 continue               # preserve per-tenant FIFO
             plen = len(req.prompt)
             need = -(-(plen + req.max_new_tokens) // self.page)
-            # prefix-shared pages cost no new capacity: charge admission
-            # credits only for the uncovered suffix
-            probe = self.mmu.probe_prefix(req.prompt)
-            need -= probe // self.page
-            if need > self.mmu.config.n_pages - (
-                    self.mmu.utilization()["pages_used"]):
+            with TraceAnnotation("repro.mmu.admit", pages=need):
+                # prefix-shared pages cost no new capacity: charge
+                # admission credits only for the uncovered suffix
+                probe = self.mmu.probe_prefix(req.prompt)
+                need -= probe // self.page
+                fits = need <= self.mmu.config.n_pages - (
+                    self.mmu.utilization()["pages_used"])
+                if fits:
+                    i = free.pop(0)
+                    # a row that will chunk-prefill must NOT publish its
+                    # prompt pages into the prefix index yet: the pages
+                    # exist at admission but their KV lands over later
+                    # steps — a sharer admitted in between would read
+                    # unwritten KV.  Publication happens when the final
+                    # chunk lands (_prefill_chunks).
+                    will_chunk = (self.prefill_chunk is not None
+                                  and plen - probe > self.prefill_chunk)
+                    covered = self.mmu.alloc_seq(req.rid, plen, slot=i,
+                                                 prompt_tokens=req.prompt,
+                                                 publish=not will_chunk)
+            if not fits:
                 blocked.add(req.tid)   # page credits exhausted for this
                 continue               # size; try smaller ones behind it
-            i = free.pop(0)
-            # a row that will chunk-prefill must NOT publish its prompt
-            # pages into the prefix index yet: the pages exist at
-            # admission but their KV lands over later steps — a sharer
-            # admitted in between would read unwritten KV.  Publication
-            # happens when the final chunk lands (_prefill_chunks).
-            will_chunk = (self.prefill_chunk is not None
-                          and plen - probe > self.prefill_chunk)
-            covered = self.mmu.alloc_seq(req.rid, plen, slot=i,
-                                         prompt_tokens=req.prompt,
-                                         publish=not will_chunk)
             self.slots[i] = req
             taken.add(qi)
             if will_chunk:
@@ -381,6 +387,7 @@ class ServingEngine:
                                if qi not in taken)
         if oneshot:
             self._prefill_batch(oneshot)
+        return len(taken)
 
     def _prefill_chunks(self) -> None:
         """Advance every chunk-prefilling row by ONE chunk.
@@ -404,43 +411,10 @@ class ServingEngine:
             else:
                 inter.append((i, req))
         if inter:
-            t0 = time.perf_counter()
-            n = len(inter)
-            nb = _bucket(n, self.max_batch)
-            chunk = self.prefill_chunk
-            smax = max(len(r.prompt) for _, r in inter)
-            maxp = max(self.max_pages,
-                       -(-_bucket(smax, 1 << 30) // self.page))
-            tables = np.full((nb, maxp), -1, np.int32)
-            tables[:n] = self.mmu.block_table(
-                [req.rid for _, req in inter], maxp)
-            q_starts = np.zeros((nb,), np.int32)
-            q_lens = np.zeros((nb,), np.int32)
-            tokens = np.zeros((nb, chunk), np.int32)
-            for j, (_, req, ) in enumerate(inter):
-                q_starts[j] = req.prefill_pos
-                q_lens[j] = chunk
-                tokens[j] = req.prompt[req.prefill_pos:
-                                       req.prefill_pos + chunk]
-            self.pools = self._prefill_chunk(
-                self.params, self.pools, jnp.asarray(tokens),
-                jnp.asarray(q_lens), jnp.asarray(q_starts),
-                jnp.asarray(tables))
-            jax.block_until_ready(self.pools["k"])
-            n_tok = n * chunk
-            self.prefill_computed += n_tok
-            self.ewma_prefill_s_per_tok = self._ewma(
-                self.ewma_prefill_s_per_tok,
-                (time.perf_counter() - t0) / n_tok)
-            self.prefill_obs += 1
-            for _, req in inter:
-                # the chunk's KV just landed in pages allocated at
-                # admission — dirty them NOW, not at alloc time, so a
-                # pre-copy round between alloc and write can't clear
-                # the flag before the content exists
-                self.mmu.mark_dirty_range(req.rid, req.prefill_pos,
-                                          req.prefill_pos + chunk)
-                req.prefill_pos += chunk
+            with TraceAnnotation("repro.engine.prefill", kind="chunk",
+                                 rows=len(inter),
+                                 tokens=len(inter) * self.prefill_chunk):
+                self._prefill_chunk_batch(inter)
         if finals:
             batch = []
             for i, req in finals:
@@ -458,6 +432,48 @@ class ServingEngine:
             for _, req in finals:
                 self.mmu.publish_prefix(req.rid, req.prompt)
 
+    def _prefill_chunk_batch(self, inter) -> None:
+        """One padded ``prefill_chunk_paged`` forward that advances each
+        of the rows ``inter`` (slot, request) by one full chunk."""
+        t0 = time.perf_counter()
+        n = len(inter)
+        nb = _bucket(n, self.max_batch)
+        chunk = self.prefill_chunk
+        smax = max(len(r.prompt) for _, r in inter)
+        maxp = max(self.max_pages,
+                   -(-_bucket(smax, 1 << 30) // self.page))
+        tables = np.full((nb, maxp), -1, np.int32)
+        tables[:n] = self.mmu.block_table(
+            [req.rid for _, req in inter], maxp)
+        q_starts = np.zeros((nb,), np.int32)
+        q_lens = np.zeros((nb,), np.int32)
+        tokens = np.zeros((nb, chunk), np.int32)
+        for j, (_, req, ) in enumerate(inter):
+            q_starts[j] = req.prefill_pos
+            q_lens[j] = chunk
+            tokens[j] = req.prompt[req.prefill_pos:
+                                   req.prefill_pos + chunk]
+        self.pools = self._prefill_chunk(
+            self.params, self.pools, jnp.asarray(tokens),
+            jnp.asarray(q_lens), jnp.asarray(q_starts),
+            jnp.asarray(tables))
+        with TraceAnnotation("repro.engine.prefill_wait"):
+            jax.block_until_ready(self.pools["k"])
+        n_tok = n * chunk
+        self.prefill_computed += n_tok
+        self.ewma_prefill_s_per_tok = self._ewma(
+            self.ewma_prefill_s_per_tok,
+            (time.perf_counter() - t0) / n_tok)
+        self.prefill_obs += 1
+        for _, req in inter:
+            # the chunk's KV just landed in pages allocated at
+            # admission — dirty them NOW, not at alloc time, so a
+            # pre-copy round between alloc and write can't clear
+            # the flag before the content exists
+            self.mmu.mark_dirty_range(req.rid, req.prefill_pos,
+                                      req.prefill_pos + chunk)
+            req.prefill_pos += chunk
+
     def _prefill_batch(self, rows) -> None:
         """One padded forward for a batch of prefill-finishing rows.
 
@@ -474,98 +490,105 @@ class ServingEngine:
         Prefill accounting (prefill_computed/skipped) is the CALLER's
         job — chunked rows bill incrementally as chunks land.
         """
-        t0 = time.perf_counter()
-        n = len(rows)
-        nb = _bucket(n, self.max_batch)
-        smax = max(len(r.prompt) for _, r, _, _ in rows)
-        # prompts may exceed max_len (such requests finish right after
-        # prefill): size the prefill tables for the longest prompt
-        maxp = max(self.max_pages, -(-_bucket(smax, 1 << 30) // self.page))
-        temps = np.zeros((nb,), np.float32)
-        topks = np.zeros((nb,), np.int32)
-        topps = np.ones((nb,), np.float32)
-        tables = np.full((nb, maxp), -1, np.int32)
-        tables[:n] = self.mmu.block_table(
-            [req.rid for _, req, _, _ in rows], maxp)
-        q_starts = np.zeros((nb,), np.int32)
-        q_lens = np.zeros((nb,), np.int32)
-        write_from = np.zeros((nb,), np.int32)
-        for j, (_, req, qstart, wfrom) in enumerate(rows):
-            temps[j] = req.temperature
-            topks[j] = req.top_k
-            topps[j] = req.top_p
-            q_starts[j] = qstart
-            q_lens[j] = len(req.prompt) - qstart
-            write_from[j] = wfrom
-        sb = _bucket(int(q_lens.max()), 1 << 30)
-        tokens = np.zeros((nb, sb), np.int32)
-        for j, (_, req, qstart, _) in enumerate(rows):
-            tokens[j, :q_lens[j]] = req.prompt[qstart:]
-        seq_ids = np.zeros((nb,), np.int32)
-        for j, (_, req, _, _) in enumerate(rows):
-            seq_ids[j] = req.rid
-        first, self.pools, self.rng = self._prefill_shared(
-            self.params, self.pools, jnp.asarray(tokens),
-            jnp.asarray(q_lens), jnp.asarray(q_starts),
-            jnp.asarray(write_from), jnp.asarray(tables), self.rng,
-            jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps),
-            jnp.asarray(seq_ids))
-        first = np.asarray(first)
-        now = time.perf_counter()
-        for _, req, _, wfrom in rows:
-            # prefill KV for [write_from, plen) just landed (pre-copy
-            # dirty tracking; see _prefill_chunks)
-            self.mmu.mark_dirty_range(req.rid, wfrom, len(req.prompt))
-        self.ewma_prefill_s_per_tok = self._ewma(
-            self.ewma_prefill_s_per_tok,
-            (now - t0) / max(int(q_lens.sum()), 1))
-        self.prefill_obs += 1
-        slots_i, srows = [], []
-        for j, (i, req, _, _) in enumerate(rows):
-            tok = int(first[j])
-            req.out_tokens.append(tok)
-            req.t_first_token = now
-            self.mmu.extend_seq(req.rid, 1, slot=i)
-            self.tokens_out += 1
-            if len(req.prompt) + 1 >= self.max_len:
-                # no decode budget left: complete straight from prefill
-                req.done = True
-                req.t_done = now
-                self.mmu.free_seq(req.rid)
-                self.block_table.unbind(i)
-                self.completed.append(req)
-                self.slots[i] = None
+        with TraceAnnotation("repro.engine.prefill", kind="shared",
+                             rows=len(rows)) as span:
+            t0 = time.perf_counter()
+            n = len(rows)
+            nb = _bucket(n, self.max_batch)
+            smax = max(len(r.prompt) for _, r, _, _ in rows)
+            # prompts may exceed max_len (such requests finish right after
+            # prefill): size the prefill tables for the longest prompt
+            maxp = max(self.max_pages,
+                       -(-_bucket(smax, 1 << 30) // self.page))
+            temps = np.zeros((nb,), np.float32)
+            topks = np.zeros((nb,), np.int32)
+            topps = np.ones((nb,), np.float32)
+            tables = np.full((nb, maxp), -1, np.int32)
+            tables[:n] = self.mmu.block_table(
+                [req.rid for _, req, _, _ in rows], maxp)
+            q_starts = np.zeros((nb,), np.int32)
+            q_lens = np.zeros((nb,), np.int32)
+            write_from = np.zeros((nb,), np.int32)
+            for j, (_, req, qstart, wfrom) in enumerate(rows):
+                temps[j] = req.temperature
+                topks[j] = req.top_k
+                topps[j] = req.top_p
+                q_starts[j] = qstart
+                q_lens[j] = len(req.prompt) - qstart
+                write_from[j] = wfrom
+            span.set_metadata(tokens=int(q_lens.sum()))
+            sb = _bucket(int(q_lens.max()), 1 << 30)
+            tokens = np.zeros((nb, sb), np.int32)
+            for j, (_, req, qstart, _) in enumerate(rows):
+                tokens[j, :q_lens[j]] = req.prompt[qstart:]
+            seq_ids = np.zeros((nb,), np.int32)
+            for j, (_, req, _, _) in enumerate(rows):
+                seq_ids[j] = req.rid
+            first, self.pools, self.rng = self._prefill_shared(
+                self.params, self.pools, jnp.asarray(tokens),
+                jnp.asarray(q_lens), jnp.asarray(q_starts),
+                jnp.asarray(write_from), jnp.asarray(tables), self.rng,
+                jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps),
+                jnp.asarray(seq_ids))
+            with TraceAnnotation("repro.engine.prefill_wait"):
+                first = np.asarray(first)
+            now = time.perf_counter()
+            for _, req, _, wfrom in rows:
+                # prefill KV for [write_from, plen) just landed (pre-copy
+                # dirty tracking; see _prefill_chunks)
+                self.mmu.mark_dirty_range(req.rid, wfrom, len(req.prompt))
+            self.ewma_prefill_s_per_tok = self._ewma(
+                self.ewma_prefill_s_per_tok,
+                (now - t0) / max(int(q_lens.sum()), 1))
+            self.prefill_obs += 1
+            slots_i, srows = [], []
+            for j, (i, req, _, _) in enumerate(rows):
+                tok = int(first[j])
+                req.out_tokens.append(tok)
+                req.t_first_token = now
+                self.mmu.extend_seq(req.rid, 1, slot=i)
+                self.tokens_out += 1
+                if len(req.prompt) + 1 >= self.max_len:
+                    # no decode budget left: complete straight from
+                    # prefill
+                    req.done = True
+                    req.t_done = now
+                    self.mmu.free_seq(req.rid)
+                    self.block_table.unbind(i)
+                    self.completed.append(req)
+                    self.slots[i] = None
+                    if self.token_sink is not None:
+                        self.token_sink(req, tok, True)
+                    continue
                 if self.token_sink is not None:
-                    self.token_sink(req, tok, True)
-                continue
-            if self.token_sink is not None:
-                self.token_sink(req, tok, False)
-            slots_i.append(i)
-            # write position of the NEXT decode step's token
-            srows.append((len(req.prompt), tok, req.temperature,
-                          req.top_k, req.top_p, req.rid))
-        if slots_i:
-            self._sync_slot_state(slots_i, srows)
+                    self.token_sink(req, tok, False)
+                slots_i.append(i)
+                # write position of the NEXT decode step's token
+                srows.append((len(req.prompt), tok, req.temperature,
+                              req.top_k, req.top_p, req.rid))
+            if slots_i:
+                self._sync_slot_state(slots_i, srows)
 
     def _sync_slot_state(self, slots_i, rows) -> None:
         """Push slot-transition deltas into the device-resident state
         (admissions and frees only — never on the per-step path).
         ``rows`` is a list of (len, token, temperature, top_k, top_p,
         rid)."""
-        idx = jnp.asarray(slots_i, jnp.int32)
-        lens, toks, temps, topks, topps, rids = zip(*rows)
-        self.dev_rids = self.dev_rids.at[idx].set(
-            jnp.asarray(rids, jnp.int32))
-        self.dev_lens = self.dev_lens.at[idx].set(
-            jnp.asarray(lens, jnp.int32))
-        self.dev_tokens = self.dev_tokens.at[idx].set(
-            jnp.asarray(toks, jnp.int32))
-        self.dev_temps = self.dev_temps.at[idx].set(
-            jnp.asarray(temps, jnp.float32))
-        self.dev_topk = self.dev_topk.at[idx].set(
-            jnp.asarray(topks, jnp.int32))
-        self.dev_topp = self.dev_topp.at[idx].set(
-            jnp.asarray(topps, jnp.float32))
+        with TraceAnnotation("repro.engine.slot_sync", rows=len(slots_i)):
+            idx = jnp.asarray(slots_i, jnp.int32)
+            lens, toks, temps, topks, topps, rids = zip(*rows)
+            self.dev_rids = self.dev_rids.at[idx].set(
+                jnp.asarray(rids, jnp.int32))
+            self.dev_lens = self.dev_lens.at[idx].set(
+                jnp.asarray(lens, jnp.int32))
+            self.dev_tokens = self.dev_tokens.at[idx].set(
+                jnp.asarray(toks, jnp.int32))
+            self.dev_temps = self.dev_temps.at[idx].set(
+                jnp.asarray(temps, jnp.float32))
+            self.dev_topk = self.dev_topk.at[idx].set(
+                jnp.asarray(topks, jnp.int32))
+            self.dev_topp = self.dev_topp.at[idx].set(
+                jnp.asarray(topps, jnp.float32))
 
     def _sample(self, logits: np.ndarray, temperature: float,
                 top_k: int = 0, top_p: float = 1.0) -> np.ndarray:
@@ -593,22 +616,34 @@ class ServingEngine:
 
     # ------------------------------------------------------------ decode ----
     def step(self) -> int:
-        """One continuous-batching engine step; returns tokens emitted."""
-        if self.shell is not None:
-            health = getattr(self.shell, "health", None)
-            if health is not None:
-                health.beat(self.slot)      # watchdog: slot is decoding
-        self._settle_io()
-        if self.admission_hook is not None:
-            self.admission_hook(self)
-        self._admit()
-        self._prefill_chunks()
-        # decode runs over BOUND rows only: chunk-prefilling rows hold a
-        # slot + pages but emit nothing until their final chunk lands
-        live = [i for i, r in enumerate(self.slots)
-                if r is not None and r.prefill_pos < 0]
-        if not live:
-            return 0
+        """One continuous-batching engine step; returns tokens emitted.
+
+        Each part of the step's host work runs inside a profiler span
+        (``repro.*``, ``docs/tracing.md``); a span costs about a
+        microsecond when no profiler is running."""
+        with TraceAnnotation("repro.engine.step") as span:
+            with TraceAnnotation("repro.health.beat"):
+                if self.shell is not None:
+                    health = getattr(self.shell, "health", None)
+                    if health is not None:
+                        health.beat(self.slot)  # watchdog: slot decoding
+                self._settle_io()
+            if self.admission_hook is not None:
+                self.admission_hook(self)
+            with TraceAnnotation("repro.engine.admit") as admit:
+                admit.set_metadata(admitted=self._admit())
+            self._prefill_chunks()
+            # decode runs over BOUND rows only: chunk-prefilling rows hold
+            # a slot + pages but emit nothing until their final chunk lands
+            held = [i for i, r in enumerate(self.slots) if r is not None]
+            live = [i for i in held if self.slots[i].prefill_pos < 0]
+            emitted = self._decode(live) if live else 0
+            span.set_metadata(live=len(held), emitted=emitted)
+        return emitted
+
+    def _decode(self, live: List[int]) -> int:
+        """One fused decode step over the bound rows ``live``, then the
+        host bookkeeping of its tokens; returns tokens emitted."""
         t0 = time.perf_counter()
         tables = self.block_table.device_view()
         # rows whose mapping changed (page crossing, eviction, fault-back)
@@ -628,43 +663,51 @@ class ServingEngine:
                   self.slots[i].top_k,
                   self.slots[i].top_p,
                   self.slots[i].rid) for i in upd])
-        next_toks, self.pools, self.dev_lens, self.rng = self._decode_step(
-            self.params, self.pools, tables, self.dev_lens,
-            self.dev_tokens, self.rng, self.dev_temps, self.dev_topk,
-            self.dev_topp, self.dev_rids)
+        with TraceAnnotation("repro.engine.decode_dispatch"):
+            next_toks, self.pools, self.dev_lens, self.rng = \
+                self._decode_step(
+                    self.params, self.pools, tables, self.dev_lens,
+                    self.dev_tokens, self.rng, self.dev_temps,
+                    self.dev_topk, self.dev_topp, self.dev_rids)
         self.dev_tokens = next_toks
         # the ONLY per-step device->host sync: the (B,) int32 token vector
-        toks = np.asarray(next_toks)
+        with TraceAnnotation("repro.engine.decode_wait"):
+            toks = np.asarray(next_toks)
         self.ewma_decode_step_s = self._ewma(
             self.ewma_decode_step_s, time.perf_counter() - t0)
         self.decode_obs += 1
         self.steps += 1
         self._submit_step_io(n_live=len(live))
 
-        emitted = 0
+        # MMU and row state first, in one pass; then the tokens go out in
+        # the same row order
+        out: List[Tuple[Request, int]] = []
         freed = []
-        for i in live:
-            req = self.slots[i]
-            tok = int(toks[i])
-            req.out_tokens.append(tok)
-            emitted += 1
-            self.mmu.extend_seq(req.rid, 1, slot=i)
-            total = len(req.prompt) + len(req.out_tokens)
-            if (len(req.out_tokens) >= req.max_new_tokens
-                    or total >= self.max_len):
-                req.done = True
-                req.t_done = time.perf_counter()
-                self.mmu.free_seq(req.rid)
-                self.block_table.unbind(i)
-                self.completed.append(req)
-                self.slots[i] = None
-                freed.append(i)
-            if self.token_sink is not None:
+        with TraceAnnotation("repro.mmu.extend") as span:
+            for i in live:
+                req = self.slots[i]
+                tok = int(toks[i])
+                req.out_tokens.append(tok)
+                self.mmu.extend_seq(req.rid, 1, slot=i)
+                total = len(req.prompt) + len(req.out_tokens)
+                if (len(req.out_tokens) >= req.max_new_tokens
+                        or total >= self.max_len):
+                    req.done = True
+                    req.t_done = time.perf_counter()
+                    self.mmu.free_seq(req.rid)
+                    self.block_table.unbind(i)
+                    self.completed.append(req)
+                    self.slots[i] = None
+                    freed.append(i)
+                out.append((req, tok))
+            span.set_metadata(rows=len(live), freed=len(freed))
+        if self.token_sink is not None:
+            for req, tok in out:
                 self.token_sink(req, tok, req.done)
         if freed:
             self._sync_slot_state(freed, [(0, 0, 0.0, 0, 1.0, 0)] * len(freed))
-        self.tokens_out += emitted
-        return emitted
+        self.tokens_out += len(out)
+        return len(out)
 
     # ---------------------------------------------------------- billing ----
     def _submit_step_io(self, n_live: int) -> None:

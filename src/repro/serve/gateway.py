@@ -45,6 +45,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.faults import FaultKind
 from repro.core.port import Invocation, PortError
@@ -238,11 +239,16 @@ class ServingGateway:
         return stream.deadline - now - (est or 0.0)
 
     def _backfill(self, engine) -> None:
-        """Engine admission hook — runs before ``_admit`` every step.
+        """Engine admission hook — runs before ``_admit`` every step."""
+        with TraceAnnotation("repro.gateway.backfill") as span:
+            before = self.dispatched
+            self._feed(engine)
+            span.set_metadata(dispatched=self.dispatched - before)
 
-        Expires dead entries, ages priorities, orders the queue by
+    def _feed(self, engine) -> None:
+        """Expire dead entries, age priorities, order the queue by
         (effective priority desc, deadline slack asc, arrival asc), and
-        feeds the engine exactly as many requests as it can place this
+        feed the engine exactly as many requests as it can place this
         step (continuous) or a full wave when idle (wave)."""
         if not self.queue:
             return
