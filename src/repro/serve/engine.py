@@ -663,6 +663,14 @@ class ServingEngine:
                   self.slots[i].top_k,
                   self.slots[i].top_p,
                   self.slots[i].rid) for i in upd])
+        with TraceAnnotation("repro.engine.decode_pages") as span:
+            # pages the kernel reads per layer (each row's context with
+            # the token being appended) against the block table's width
+            span.set_metadata(
+                live_pages=sum(-(-(len(self.slots[i].prompt)
+                                   + len(self.slots[i].out_tokens))
+                                 // self.page) for i in live),
+                table_pages=self.max_batch * self.max_pages)
         with TraceAnnotation("repro.engine.decode_dispatch"):
             next_toks, self.pools, self.dev_lens, self.rng = \
                 self._decode_step(

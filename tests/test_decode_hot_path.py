@@ -313,3 +313,33 @@ def test_engine_per_request_filters_keep_single_trace(served):
     eng.run()
     assert TRACE_COUNTS["decode_step_paged"] - before == 1
     assert len(eng.completed) == 2
+
+
+def test_decode_pages_span_counts_kernel_pages(served, tmp_path):
+    """Each decode step records, before its dispatch, the pages the paged
+    kernel reads per layer (each decoding row's context with the token
+    being appended, in pages) and the block table's width over all rows."""
+    cfg, params = served
+    page, max_batch, max_len = 16, 3, 128
+    mmu = MMU(MMUConfig(page_size=page, n_pages=128))
+    eng = ServingEngine(cfg, params, mmu, max_batch=max_batch,
+                        max_len=max_len)
+    prompts = [list(range(3, 3 + n)) for n in (15, 40)]
+    for p in prompts:
+        eng.submit(p, max_new_tokens=5)
+    eng.step()                                # prefill, first decode
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run()
+    (xplane,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = [e for plane in jax.profiler.ProfileData.from_file(
+        str(xplane)).planes for line in plane.lines for e in line.events]
+    pages = [dict(e.stats) for e in events
+             if e.name == "repro.engine.decode_pages"]
+    dispatches = [e for e in events
+                  if e.name == "repro.engine.decode_dispatch"]
+    assert len(pages) == len(dispatches) == 3
+    # from the second decode on: 2, 3, 4 tokens past each prompt
+    assert [p["live_pages"] for p in pages] == [
+        sum(-(-(len(q) + k) // page) for q in prompts) for k in (2, 3, 4)]
+    assert {p["table_pages"] for p in pages} == {
+        max_batch * -(-max_len // page)}

@@ -117,6 +117,85 @@ def test_paged_attention_ragged_occupancy_page_groups():
                                    atol=2e-5, err_msg=f"ppb={ppb}")
 
 
+def _case(kh=2, group=2, page=16, maxp=8, ppb=2, lens=(40, 77, 128, 9),
+          holes=()):
+    return dict(kh=kh, group=group, page=page, maxp=maxp, ppb=ppb,
+                lens=lens, holes=holes)
+
+
+# one grid step holds every kv head of a row: head and group counts of
+# phi3 (10 kv / 4), smollm (3 / 3), a TP=2 shard of phi3 (5) and MQA (1)
+PA_SHAPE_CASES = {
+    f"kv{kh}_g{g}": _case(kh=kh, group=g)
+    for kh in (1, 3, 5, 10) for g in (1, 3, 4)}
+PA_SHAPE_CASES.update({
+    "len_mid_page": _case(lens=(1, 17, 45, 100)),
+    "len_group_boundary": _case(lens=(32, 64, 96, 128)),
+    "len_full_width": _case(lens=(128, 128, 127, 113)),
+    "empty_rows": _case(lens=(0, 50, 0, 0)),
+    "hole_in_live_range": _case(lens=(100, 128, 60, 33),
+                                holes=((0, 2), (1, 0), (1, 7), (2, 3))),
+    "width_not_ppb_multiple": _case(maxp=7, ppb=3, lens=(112, 111, 49, 0)),
+    "ppb_over_width": _case(maxp=3, ppb=4, lens=(48, 20, 33, 1)),
+})
+
+
+def _pa_inputs(kh, group, page, maxp, lens, holes, d=64, npages=48,
+               seed=11):
+    b = len(lens)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (b, kh * group, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (npages, kh, page, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (npages, kh, page, d), jnp.float32)
+    lens = np.asarray(lens, np.int32)
+    tables = _tables(b, page, maxp, npages, lens)
+    for i, j in holes:                     # host-swapped page: unmapped
+        tables[i, j] = -1
+    return q, kp, vp, tables, lens
+
+
+@pytest.mark.parametrize("name", sorted(PA_SHAPE_CASES))
+def test_paged_attention_heads_and_lengths(name):
+    """Every kv head of a row in one grid step, over head/group counts,
+    lengths mid-page, on a group boundary and at the full table width,
+    empty rows, -1 holes inside the live range, and a table width that
+    is not a multiple of the page group: the oracle's output."""
+    c = PA_SHAPE_CASES[name]
+    q, kp, vp, tables, lens = _pa_inputs(c["kh"], c["group"], c["page"],
+                                         c["maxp"], c["lens"], c["holes"])
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(lens))
+    out = paged_attention(*args, pages_per_block=c["ppb"], interpret=True)
+    ref = paged_attention_ref(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("ppb", [1, 2, 3, 4])
+def test_paged_attention_never_reads_dead_pages(ppb):
+    """Table entries past each row's last live page (in its last live
+    group and in every group after it) map real pages full of NaN: the
+    kernel never reads them, so the output is the oracle's with those
+    entries unmapped (-1)."""
+    kh, group, page, maxp, npages = 2, 3, 16, 9, 40
+    lens = (1, 16, 17, 50, 0, 144)
+    q, kp, vp, _, lens = _pa_inputs(kh, group, page, maxp, lens, (),
+                                    npages=npages)
+    nan_pages = np.arange(npages - 8, npages)
+    kp = kp.at[nan_pages].set(jnp.nan)
+    vp = vp.at[nan_pages].set(jnp.nan)
+    tables = _tables(len(lens), page, maxp, nan_pages[0], lens)
+    poisoned = tables.copy()
+    for i, n in enumerate(-(-lens // page)):
+        dead = np.arange(n, maxp)
+        poisoned[i, dead] = nan_pages[dead % len(nan_pages)]
+    out = paged_attention(q, kp, vp, jnp.asarray(poisoned),
+                          jnp.asarray(lens), pages_per_block=ppb,
+                          interpret=True)
+    ref = paged_attention_ref(q, kp, vp, jnp.asarray(tables),
+                              jnp.asarray(lens))
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
 def test_paged_matches_dense_attention():
     """Paged attention over scattered pages == dense attention over the
     same logical sequence (the MMU indirection is value-invisible)."""

@@ -80,6 +80,30 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kv_shards", [1, 2], ids=["one_chip", "tp2_shard"])
+def test_paged_attention_compiles_for_v5e_phi3_widths(
+        one_chip, no_persistent_cache, kv_shards):
+    """The kernel at the phi3-medium serving cell's shapes: batch 16,
+    40 q / 10 kv heads (a TP=2 shard holds 20 / 5), head_dim 128, page
+    32, a 128-page table (4096 tokens) over an 8-layer flat float32 pool
+    of 1024 pages a layer.  One grid step fetches all kv heads of a page
+    group, so its VMEM blocks are the widest the serving path asks for."""
+    cfg = get_config("phi3-medium-14b")
+    h, kh = cfg.n_heads // kv_shards, cfg.n_kv_heads // kv_shards
+    hd, batch, page, max_pages = cfg.resolved_head_dim, 16, 32, 128
+    n_pages = 8 * 1024
+    f32 = jnp.float32
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((batch, h, hd), f32),
+        jax.ShapeDtypeStruct((n_pages, kh, page, hd), f32),
+        jax.ShapeDtypeStruct((n_pages, kh, page, hd), f32),
+        jax.ShapeDtypeStruct((batch, max_pages), jnp.int32),
+        jax.ShapeDtypeStruct((batch,), jnp.int32)))
+    compiled = jax.jit(
+        lambda *a: paged_attention(*a, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_full_width_decode_step_compiles_for_v5e(one_chip,
                                                  no_persistent_cache,
                                                  monkeypatch):
