@@ -2,8 +2,11 @@
 
 Everything that belongs to one configuration, traffic mix or metric is
 found by name: ``BENCHMARK.json`` names the cell's configuration file
-and traffic mix (``chipbench/traffic/<traffic>.json``), and each metric
-is read by ``chipbench/metrics/<metric>.py``.
+and traffic mix (``chipbench/traffic/<traffic>.json``), the
+configuration file names its architecture (``"arch"``, default
+``dense``: ``chipbench/archs/<arch>.py``, which gives its sizes, program
+config, weights, reference and counts), and each metric is read by
+``chipbench/metrics/<metric>.py``.
 
 The path under test is the program's normal one: ``Shell`` with the MMU
 service -> ``ServingGateway(admission="fifo")`` -> ``ServingEngine`` on
@@ -15,6 +18,7 @@ open loop, and times each request from when it was due; a mix with
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import importlib.util
 import json
@@ -29,7 +33,6 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from chipbench import traffic as T
-from chipbench.model import dims_of, load_config, program_config
 
 ROOT = Path(__file__).resolve().parents[1]
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -48,6 +51,7 @@ class Cell:
     name: str
     chips: int
     conf: dict
+    arch: object               # the configuration's chipbench/archs module
     mix: dict
     metrics: List[dict]        # entries of end_to_end or per_layer
 
@@ -59,7 +63,7 @@ def load_cell(name: str, trace: bool, root: Path = ROOT) -> Cell:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     confs = {c["name"]: c for c in bench["configs"]}
-    conf = load_config(root / confs[w["config"]]["file"])
+    conf = json.loads((root / confs[w["config"]]["file"]).read_text())
     mix = T.load_mix(root / "chipbench" / "traffic" / f"{w['traffic']}.json")
     group = bench["per_layer" if trace else "end_to_end"]
     e2e = {m["name"]: m for m in bench["end_to_end"]}
@@ -70,18 +74,33 @@ def load_cell(name: str, trace: bool, root: Path = ROOT) -> Cell:
         if trace:       # a per-layer metric follows its end-to-end metric
             return reported(e2e[m["moves"]])
         return True
-    return Cell(name=name, chips=int(w["chips"]), conf=conf, mix=mix,
+    return Cell(name=name, chips=int(w["chips"]), conf=conf,
+                arch=load_arch(conf.get("arch", "dense"), root), mix=mix,
                 metrics=[m for m in group if reported(m)])
 
 
-def load_reader(metric: str, root: Path = ROOT):
-    path = root / "chipbench" / "metrics" / f"{metric}.py"
+def _load(kind: str, name: str, root: Path):
+    path = root / "chipbench" / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + metric.replace(".", "_").replace("-", "_"),
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"),
         path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # dataclasses look their module up
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str, root: Path = ROOT):
+    return _load("metrics", metric, root).read
+
+
+def load_arch(name: str, root: Path = ROOT):
+    """``chipbench/archs/<name>.py`` of the checkout at ``root``, loaded
+    once per process and checkout, so its jitted programs compile once."""
+    return _load_once("archs", name, Path(root))
+
+
+_load_once = functools.cache(_load)
 
 
 # --------------------------------------------------------------- records --
@@ -100,7 +119,8 @@ class Run:
     """What a run recorded; the metric readers take their numbers here."""
     cell: str
     arrivals: str                       # "poisson" | "backlog"
-    dims: object
+    arch: object                        # the configuration's archs module
+    dims: object                        # arch.dims_of of the configuration
     device_kind: str
     setup_s: float = 0.0
     t_open: float = 0.0
@@ -407,14 +427,12 @@ def sample_for_check(run: Run, seed: int) -> List[Req]:
     return out
 
 
-def check(weights, dims, sample: List[Req], pad_to: int,
+def check(arch, weights, dims, sample: List[Req], pad_to: int,
           control: bool = False):
-    from chipbench import reference
     prog, ctrl, n = 0.0, 0.0, 0
     for r in sample:
-        g, c = reference.gaps(weights, dims, r.arrival.prompt,
-                              r.stream.tokens, pad_to=pad_to,
-                              control=control)
+        g, c = arch.gaps(weights, dims, r.arrival.prompt, r.stream.tokens,
+                         pad_to=pad_to, control=control)
         prog = max(prog, float(g.max()))
         n += len(g)
         if control:
@@ -478,15 +496,14 @@ def run_cell(argv=None, *, t0: Optional[float] = None, root: Path = ROOT):
         lambda ev, secs, **kw: compiles.__setitem__(
             0, compiles[0] + (ev == BACKEND_COMPILE)))
 
-    from chipbench.weights import make_weights
     from repro.serve.paged_model import TRACE_COUNTS
-    conf = cell.conf
-    dims = dims_of(conf, rehearse=args.rehearse)
+    conf, arch = cell.conf, cell.arch
+    dims = arch.dims_of(conf, rehearse=args.rehearse)
     geo = conf["rehearse_engine" if args.rehearse else "engine"]
-    cfg = program_config(conf["name"], dims)
+    cfg = arch.program_config(conf["name"], dims)
     eng_seed = int(args.seed) % (1 << 31)
     t_w = time.perf_counter()
-    weights = jax.block_until_ready(make_weights(dims, args.seed))
+    weights = jax.block_until_ready(arch.make_weights(dims, args.seed))
     log(f"weights on the device: {time.perf_counter() - t_w:.3f} s "
         f"(process start to here {t_w - t0:.3f} s)")
     arrivals = T.generate(cell.mix, vocab=dims.vocab, seed=args.seed,
@@ -498,8 +515,8 @@ def run_cell(argv=None, *, t0: Optional[float] = None, root: Path = ROOT):
     if args.fault:
         break_path(eng, args.fault, dims.vocab)
     warm_up(gw, eng, geo, dims.vocab, eng_seed)
-    run = Run(cell=cell.name, arrivals=cell.mix["arrivals"], dims=dims,
-              device_kind=dev.device_kind)
+    run = Run(cell=cell.name, arrivals=cell.mix["arrivals"], arch=arch,
+              dims=dims, device_kind=dev.device_kind)
     if args.trace:
         record_prefill_calls(eng, run)
     trace_dir = root / ".chipbench_trace" / cell.name
@@ -530,7 +547,7 @@ def run_cell(argv=None, *, t0: Optional[float] = None, root: Path = ROOT):
 
     sample = sample_for_check(run, args.seed)
     t_check = time.perf_counter()
-    gap, ctrl, n_checked = check(weights, dims, sample,
+    gap, ctrl, n_checked = check(arch, weights, dims, sample,
                                  pad_to=geo["max_len"],
                                  control=args.control)
     log(f"reference check of {len(sample)} requests, {n_checked} served "

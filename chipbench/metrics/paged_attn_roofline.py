@@ -1,10 +1,10 @@
 """Share of its roofline that the paged decode-attention kernel reaches:
 the least time the chip could take for the attention the window's decode
 steps needed (the larger of bytes over peak bandwidth and FLOPs over peak
-compute, ``chipbench/costs.py``: live K and V at the stated bfloat16,
-q and o) over the kernel's device time in the traced window.  Device
-trace; the kernel is the decode program's Mosaic custom call."""
-from chipbench import costs
+compute, by the counts of the configuration's architecture,
+``chipbench/archs/``: live K and V at the stated bfloat16, q and o) over
+the kernel's device time in the traced window.  Device trace; the kernel
+is the decode program's Mosaic custom call."""
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
@@ -24,8 +24,8 @@ def read(run):
     if not secs or not rows:
         return None
     pk = run.peaks()
-    least = max(costs.kernel_bytes(run.dims, ctx_sum, rows)
+    least = max(run.arch.kernel_bytes(run.dims, ctx_sum, rows)
                 / pk["hbm_bytes_per_s"],
-                costs.attn_flops(run.dims, ctx_sum)
+                run.arch.attn_flops(run.dims, ctx_sum)
                 / pk["bf16_flops_per_s"])
     return 100.0 * least / secs

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import json
 import shutil
 import sys
 import tempfile
@@ -32,17 +33,18 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("record_trace: needs a TPU", file=sys.stderr)
         return 2
-    from chipbench.model import dims_of, load_config, program_config
-    from chipbench.weights import make_weights
+    from chipbench.harness import load_arch
     from repro.core import Shell, ShellConfig
     from repro.core.services.mmu import MMUConfig
     from repro.serve.engine import ServingEngine
     from repro.serve.gateway import ServingGateway
 
-    conf = load_config(ROOT / "chipbench/tests/data/smollm-135m.json")
-    dims = dims_of(conf, rehearse=True)
-    cfg = program_config("smollm-135m", dims)
-    w = make_weights(dims, 1)
+    conf = json.loads(
+        (ROOT / "chipbench/tests/data/smollm-135m.json").read_text())
+    arch = load_arch("dense")
+    dims = arch.dims_of(conf, rehearse=True)
+    cfg = arch.program_config("smollm-135m", dims)
+    w = arch.make_weights(dims, 1)
     shell = Shell(ShellConfig.make(
         services={"mmu": MMUConfig(page_size=16, n_pages=128)}, n_vfpgas=1))
     shell.build()

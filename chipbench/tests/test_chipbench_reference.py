@@ -1,6 +1,7 @@
 """The plain reference against the serving path's own programs at the
 reduced sizes: one-shot prefill into scattered pages, then decode steps
 through the block tables, compared logit by logit."""
+import json
 import os
 import sys
 from pathlib import Path
@@ -15,9 +16,9 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from chipbench import reference  # noqa: E402
-from chipbench.model import dims_of, load_config, program_config  # noqa
-from chipbench.weights import make_weights  # noqa: E402
+from chipbench import harness  # noqa: E402
+
+dense = harness.load_arch("dense")
 
 PAGE, N_PAGES, MAX_LEN, STEPS = 16, 64, 128, 4
 CONFIG_FILES = {
@@ -56,41 +57,41 @@ def serve_logits(cfg, params, prompts):
 
 @pytest.mark.parametrize("config", ["smollm-135m", "phi3-medium-14b-8l"])
 def test_reference_matches_serving_path(config):
-    conf = load_config(CONFIG_FILES[config])
-    dims = dims_of(conf, rehearse=True)
-    cfg = program_config(config, dims)
+    conf = json.loads(CONFIG_FILES[config].read_text())
+    dims = dense.dims_of(conf, rehearse=True)
+    cfg = dense.program_config(config, dims)
     # float32 weights: both sides then compute in float32 on the CPU, so
     # they differ only by summation order
-    w = make_weights(dims, 7, dtype=jnp.float32)
+    w = dense.make_weights(dims, 7, dtype=jnp.float32)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, dims.vocab, size=n).tolist() for n in (20, 37)]
     toks, logits = serve_logits(cfg, w, prompts)
     for i, p in enumerate(prompts):
         seq = p + toks[i].tolist()
         rows = np.arange(len(p), len(p) + STEPS)
-        ref = reference.logits_at(w, dims, seq[:len(p) + STEPS], rows,
+        ref = dense.logits_at(w, dims, seq[:len(p) + STEPS], rows,
                                   pad_to=MAX_LEN)
         got = np.stack([lg[i] for lg in logits])
         scale = np.abs(ref).max()
         assert np.abs(got - ref).max() / scale < 1e-4
-        first = reference.logits_at(w, dims, p, [len(p) - 1],
-                                    pad_to=MAX_LEN)[0]
+        first = dense.logits_at(w, dims, p, [len(p) - 1],
+                                pad_to=MAX_LEN)[0]
         assert toks[i, 0] == first.argmax()
 
 
 def test_gaps_zero_for_reference_tokens_and_control_reads_higher():
-    conf = load_config(CONFIG_FILES["smollm-135m"])
-    dims = dims_of(conf, rehearse=True)
-    w = make_weights(dims, 5)
+    conf = json.loads(CONFIG_FILES["smollm-135m"].read_text())
+    dims = dense.dims_of(conf, rehearse=True)
+    w = dense.make_weights(dims, 5)
     rng = np.random.default_rng(1)
     prompt = rng.integers(0, dims.vocab, size=40).tolist()
     served = []
     for _ in range(24):            # the reference's own greedy tokens
-        lg = reference.logits_at(w, dims, prompt + served,
-                                 [len(prompt) + len(served) - 1],
-                                 pad_to=MAX_LEN)[0]
+        lg = dense.logits_at(w, dims, prompt + served,
+                             [len(prompt) + len(served) - 1],
+                             pad_to=MAX_LEN)[0]
         served.append(int(lg.argmax()))
-    prog, ctrl = reference.gaps(w, dims, prompt, served, pad_to=MAX_LEN,
-                                control=True)
+    prog, ctrl = dense.gaps(w, dims, prompt, served, pad_to=MAX_LEN,
+                            control=True)
     assert prog.shape == (24,) and float(prog.max()) == 0.0
     assert float(ctrl.max()) > 0.0
