@@ -21,7 +21,9 @@ Hot-path invariants (the Coyote v2 "shell out of the datapath" story):
     ``self.pools`` / ``self.dev_lens`` / ``self.dev_tokens`` /
     ``self.rng`` must be reassigned from the step's return values every
     call — holding a stale reference to a donated buffer is an error.
-    The block-table view is NOT donated (the cache reuses it).
+    The block-table view is NOT donated (the cache reuses it).  The
+    slot-state sync (``update_slot_state``) donates all six per-row state
+    arrays and takes its delta as one packed transfer.
   * **One (B,) vector per step.**  Sampling (greedy argmax + Gumbel-max
     temperature) is fused inside the jitted step; the (B, vocab) logits
     tensor never leaves the device.  The only per-step host<->device
@@ -39,8 +41,9 @@ Hot-path invariants (the Coyote v2 "shell out of the datapath" story):
     a tenant whose pending I/O hits its bound stalls *itself* at submit
     (paper §7.2 containment) — that is the QoS design, not a hot-path
     regression.
-  * **One compilation.**  ``decode_step_paged`` traces exactly once per
-    (engine shape, flags) across a run regardless of occupancy changes —
+  * **One compilation.**  ``decode_step_paged`` and ``update_slot_state``
+    each trace exactly once per (engine shape, flags) across a run
+    regardless of occupancy changes —
     ``repro.serve.paged_model.TRACE_COUNTS`` is the retrace guard.
 
 Bench reproduction: ``PYTHONPATH=src python -m benchmarks.run --only
@@ -67,9 +70,10 @@ from repro.core.services.mmu import MMU, MMUConfig
 from repro.kernels import resolve_use_pallas
 from repro.serve.paged_model import (bucket_pages, decode_step_paged,
                                      flat_page_indices, gather_kv_pages,
-                                     make_pools, prefill_chunk_paged,
+                                     make_pools, pack_slot_delta,
+                                     prefill_chunk_paged,
                                      prefill_shared_paged,
-                                     scatter_kv_pages)
+                                     scatter_kv_pages, update_slot_state)
 
 
 @dataclass
@@ -195,6 +199,8 @@ class ServingEngine:
         # prefill forward vs tokens whose KV came from shared pages
         self.prefill_computed = 0
         self.prefill_skipped = 0
+        # slot-state deltas pushed to the device (_sync_slot_state calls)
+        self.slot_syncs = 0
         # Device-resident decode state: block tables (cached MMU view),
         # row lengths, last tokens, temperatures, PRNG key.
         self.block_table = mmu.block_table_device(
@@ -571,24 +577,18 @@ class ServingEngine:
 
     def _sync_slot_state(self, slots_i, rows) -> None:
         """Push slot-transition deltas into the device-resident state
-        (admissions and frees only — never on the per-step path).
+        (admissions, frees, rows whose mapping changed, migration).
         ``rows`` is a list of (len, token, temperature, top_k, top_p,
-        rid)."""
+        rid).  The delta crosses as one packed array into one compiled
+        program, whatever the number of rows."""
         with TraceAnnotation("repro.engine.slot_sync", rows=len(slots_i)):
-            idx = jnp.asarray(slots_i, jnp.int32)
-            lens, toks, temps, topks, topps, rids = zip(*rows)
-            self.dev_rids = self.dev_rids.at[idx].set(
-                jnp.asarray(rids, jnp.int32))
-            self.dev_lens = self.dev_lens.at[idx].set(
-                jnp.asarray(lens, jnp.int32))
-            self.dev_tokens = self.dev_tokens.at[idx].set(
-                jnp.asarray(toks, jnp.int32))
-            self.dev_temps = self.dev_temps.at[idx].set(
-                jnp.asarray(temps, jnp.float32))
-            self.dev_topk = self.dev_topk.at[idx].set(
-                jnp.asarray(topks, jnp.int32))
-            self.dev_topp = self.dev_topp.at[idx].set(
-                jnp.asarray(topps, jnp.float32))
+            packed = pack_slot_delta(self.max_batch, slots_i, rows)
+            (self.dev_rids, self.dev_lens, self.dev_tokens, self.dev_temps,
+             self.dev_topk, self.dev_topp) = update_slot_state(
+                self.dev_rids, self.dev_lens, self.dev_tokens,
+                self.dev_temps, self.dev_topk, self.dev_topp,
+                self._place(packed))
+            self.slot_syncs += 1
 
     def _sample(self, logits: np.ndarray, temperature: float,
                 top_k: int = 0, top_p: float = 1.0) -> np.ndarray:
@@ -1101,7 +1101,8 @@ class ServingEngine:
                  "tokens_per_s": self.tokens_out / max(dt, 1e-9),
                  "completed": len(self.completed),
                  "prefill_computed": self.prefill_computed,
-                 "prefill_skipped": self.prefill_skipped}
+                 "prefill_skipped": self.prefill_skipped,
+                 "slot_syncs": self.slot_syncs}
         stats.update(self.latency_stats())
         if self.shell is not None and self.tenant is not None:
             stats["io_drained"] = drained

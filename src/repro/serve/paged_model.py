@@ -41,6 +41,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels.paged_attention.ops import paged_decode
@@ -192,6 +193,40 @@ def scatter_kv_pages(pools, flat_idx, data):
                 data["k"].astype(pools["k"].dtype)),
             "v": pools["v"].at[flat_idx].set(
                 data["v"].astype(pools["v"].dtype))}
+
+
+def pack_slot_delta(max_batch: int, slots, rows) -> np.ndarray:
+    """The host half of :func:`update_slot_state`: one (7, max_batch)
+    int32 array holding a row mask, then rids, lens, tokens, top_k, and
+    temperature and top_p as float32 bit patterns.  ``rows`` is a list
+    of (len, token, temperature, top_k, top_p, rid), one per slot in
+    ``slots``; every other row's mask is 0."""
+    packed = np.zeros((7, max_batch), np.int32)
+    for i, (ln, tok, temp, topk, topp, rid) in zip(slots, rows):
+        packed[:, i] = (1, rid, ln, tok, topk,
+                        np.float32(temp).view(np.int32),
+                        np.float32(topp).view(np.int32))
+    return packed
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5))
+def update_slot_state(rids, lens, tokens, temps, topk, topp, packed):
+    """Write a slot-state delta (:func:`pack_slot_delta`) into the six
+    (B,) decode-state arrays; rows whose mask is 0 keep their values.
+    The shapes never vary with the number of rows written, so the
+    program compiles once per batch size.  The six arrays are donated
+    and come back in the same order."""
+    _count_trace("slot_update")
+    take = packed[0] != 0
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.float32)
+    return (jnp.where(take, packed[1], rids),
+            jnp.where(take, packed[2], lens),
+            jnp.where(take, packed[3], tokens),
+            jnp.where(take, bits(packed[5]), temps),
+            jnp.where(take, packed[4], topk),
+            jnp.where(take, bits(packed[6]), topp))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "page_size"),

@@ -57,6 +57,62 @@ def test_decode_compiles_exactly_once_across_occupancy_changes(served):
         "decode_step_paged must compile exactly once per engine shape"
 
 
+def test_slot_update_compiles_once_and_tracks_host_truth(served):
+    """The slot-state sync is one compiled program of fixed shape: over
+    admissions, frees and page crossings that write 1, 2 and max_batch
+    rows it traces once, and after every step the six device arrays hold
+    the host truth for live rows and the cleared values for freed ones
+    (a sampled, top-k and top-p row covers the float bit round trip)."""
+    cfg, params = served
+    B = 5        # a batch size unique to this test: order-independent
+    mmu = MMU(MMUConfig(page_size=4, n_pages=128))
+    eng = ServingEngine(cfg, params, mmu, max_batch=B, max_len=64, seed=2)
+    sizes = []
+    sync = eng._sync_slot_state
+
+    def counting_sync(slots_i, rows):
+        sizes.append(len(slots_i))
+        sync(slots_i, rows)
+    eng._sync_slot_state = counting_sync
+    for n, new, temp, k, p in ((5, 2, 0.0, 0, 1.0), (7, 3, 0.0, 0, 1.0),
+                               (9, 3, 0.7, 3, 0.8), (6, 8, 1.3, 0, 1.0),
+                               (11, 8, 0.0, 0, 0.9)):
+        eng.submit(list(range(3, 3 + n)), max_new_tokens=new,
+                   temperature=temp, top_k=k, top_p=p)
+    before = TRACE_COUNTS.get("slot_update", 0)
+
+    def device_state():
+        return {f: np.asarray(getattr(eng, "dev_" + f))
+                for f in ("rids", "lens", "tokens", "temps", "topk", "topp")}
+    held = [None] * B
+    while eng.pending():
+        eng.step()
+        got = device_state()
+        for i, req in enumerate(eng.slots):
+            if req is not None:
+                want = {"rids": req.rid,
+                        "lens": len(req.prompt) + len(req.out_tokens) - 1,
+                        "tokens": req.out_tokens[-1],
+                        "temps": np.float32(req.temperature),
+                        "topk": req.top_k, "topp": np.float32(req.top_p)}
+            elif held[i] is not None:        # freed in this step
+                want = {"rids": 0, "lens": 0, "tokens": 0,
+                        "temps": np.float32(0.0), "topk": 0,
+                        "topp": np.float32(1.0)}
+            else:      # empty before: decode moves only lens and tokens
+                want = {"rids": 0, "temps": np.float32(0.0), "topk": 0,
+                        "topp": np.float32(1.0)}
+            for f, v in want.items():
+                assert got[f][i] == v, (eng.steps, i, f, got[f][i], v)
+        held = list(eng.slots)
+    assert len(eng.completed) == 5
+    assert {1, 2, B} <= set(sizes), sizes
+    assert eng.slot_syncs == len(sizes)
+    assert eng.run()["slot_syncs"] == len(sizes)
+    assert TRACE_COUNTS["slot_update"] - before == 1, \
+        "update_slot_state must compile exactly once per engine shape"
+
+
 def test_prefill_is_batched_one_forward_per_admit_wave(served):
     cfg, params = served
     mmu = MMU(MMUConfig(page_size=16, n_pages=128))

@@ -142,11 +142,31 @@ def test_tp2_token_parity_under_churn_and_eviction():
                 (list(range(5, 11)), 1.3, 5, 1.0),
                 (list(range(2, 14)), 0.7, 0, 0.9),
                 (list(range(9, 15)), 0.0, 0, 1.0)]
+        def state_replicated(eng):
+            # the TP decode jit keys on this sharding: a slot-state sync
+            # that moved it would retrace or fail the next step
+            for f in ("rids", "lens", "tokens", "temps", "topk", "topp"):
+                x = getattr(eng, "dev_" + f)
+                assert x.sharding == eng.tp.replicated, (f, x.sharding)
+
         for eng in (single, tp2):
             for p, t, k, tp_ in reqs:
                 eng.submit(p, max_new_tokens=10, temperature=t,
                            top_k=k, top_p=tp_)
+        # admissions, frees and re-admissions, then the TP engine hands
+        # its in-flight rows to itself through a migration snapshot
+        for _ in range(12):
+            single.step()
+            tp2.step()
+        assert len(tp2.completed) == 2 and tp2.active == 2
+        state_replicated(tp2)
+        header, arrays = tp2.snapshot_state()
+        tp2.evacuate()
+        state_replicated(tp2)
+        tp2.restore_state(header, arrays)
+        state_replicated(tp2)
         drain(single, tp2)
+        state_replicated(tp2)
         assert tokens(single) == tokens(tp2), (tokens(single), tokens(tp2))
         print("CHURN_PARITY_OK")
 
