@@ -20,7 +20,7 @@ on the same Port drain machinery:
      evict-with-copy pager already holds on the host.
   3. **Restore** — fresh page allocation on the destination MMU
      (``MMU.restore_seqs``), KV payload scattered to the new physical
-     pages, ``DeviceBlockTable`` rows rebuilt (dirty-row upload on the
+     pages, ``DeviceBlockTable`` rows rebuilt (one upload on the
      next device view), decode state and PRNG adopted, CSR/addr-map
      applied to the destination slot.
   4. **Replay** — invocations held at the source during the move are

@@ -39,10 +39,8 @@ from repro.core.services.base import Service
 
 try:                                  # device view is optional: the MMU
     import jax                       # driver half works without a device
-    import jax.numpy as jnp
 except ImportError:                  # pragma: no cover
     jax = None
-    jnp = None
 
 
 @dataclass(frozen=True)
@@ -706,12 +704,12 @@ class MMU(Service):
                            sharding=None) -> "DeviceBlockTable":
         """A cached device-resident block-table view over a fixed window
         of engine slots — the steady-state decode step reads a device
-        array that is already there; only rows whose mapping changed
-        (alloc/extend/free/evict deltas) are re-uploaded.  ``sharding``
-        (a replicated ``NamedSharding``) pins the mirror to a mesh for
-        tensor-parallel engines: one logical table, every shard reads
-        the same copy."""
-        if jnp is None:
+        array that is already there; one transfer of the host mirror
+        when any row's mapping changed (alloc/extend/free/evict deltas).
+        ``sharding`` (a replicated ``NamedSharding``) pins the mirror to
+        a mesh for tensor-parallel engines: one logical table, every
+        shard reads the same copy."""
+        if jax is None:
             raise ImportError("jax is required for MMU device block-table "
                               "views (the host-side driver works without)")
         return DeviceBlockTable(self, n_slots, max_pages, sharding=sharding)
@@ -949,13 +947,16 @@ class MMU(Service):
 
 
 class DeviceBlockTable:
-    """Incremental device mirror of the MMU block table for a slot window.
+    """Device mirror of the MMU block table for a slot window.
 
     The serving engine binds a sequence id to each slot; ``device_view()``
-    returns a (n_slots, max_pages) int32 device array, re-uploading only
-    the rows whose MMU mapping version changed since the last call.  In
-    steady-state decode (no page-boundary crossing, no slot churn) the
-    call is a pure cache hit: zero host->device traffic.
+    returns a (n_slots, max_pages) int32 device array.  It refreshes on
+    the host only the rows whose MMU mapping version changed since the
+    last call, and when any row changed, the whole host mirror goes to
+    the device in one transfer: no scatter, no compiled program, and no
+    shape that varies with the number of rows.  In steady-state decode
+    (no page-boundary crossing, no slot churn) the call is a pure cache
+    hit: zero host->device traffic.
     """
 
     def __init__(self, mmu: "MMU", n_slots: int, max_pages: int, *,
@@ -969,7 +970,8 @@ class DeviceBlockTable:
         self._host = np.full((n_slots, max_pages), -1, np.int32)
         self._dev = None
         self._stale = set(range(n_slots))
-        self.row_uploads = 0                          # rows re-uploaded
+        self.uploads = 0                              # views that transferred
+        self.row_uploads = 0                          # rows refreshed
         self.hits = 0                                 # pure cache hits
         self.last_updated_rows: list = []             # rows synced last view
 
@@ -985,7 +987,8 @@ class DeviceBlockTable:
         self._stale.add(slot)
 
     def device_view(self):
-        """(n_slots, max_pages) int32 device array, incrementally synced."""
+        """(n_slots, max_pages) int32 device array; one transfer when any
+        row changed, none otherwise."""
         with jax.profiler.TraceAnnotation("repro.mmu.block_table") as span:
             for i, sid in enumerate(self._seq):
                 if sid < 0:
@@ -996,24 +999,14 @@ class DeviceBlockTable:
                         [sid], self.max_pages)[0]
                     self._ver[i] = v
                     self._stale.add(i)
-            if self._dev is None:
-                self._dev = (jax.device_put(self._host, self.sharding)
-                             if self.sharding is not None
-                             else jnp.asarray(self._host))
-                self.row_uploads += self.n_slots
-                self.last_updated_rows = list(range(self.n_slots))
-                self._stale.clear()
-            elif self._stale:
-                rows = sorted(self._stale)
-                self._dev = self._dev.at[jnp.asarray(rows, jnp.int32)].set(
-                    jnp.asarray(self._host[rows]))
-                if self.sharding is not None:
-                    # keep the mirror pinned replicated across the mesh (the
-                    # scatter above follows the committed input, but be
-                    # explicit: the TP decode jit keys on this sharding)
-                    self._dev = jax.device_put(self._dev, self.sharding)
-                self.row_uploads += len(rows)
-                self.last_updated_rows = rows
+            if self._stale:
+                # a copy: device_put may alias the numpy buffer (CPU) or
+                # read it after returning, and bind/unbind and the refresh
+                # above write _host in place
+                self._dev = jax.device_put(self._host.copy(), self.sharding)
+                self.uploads += 1
+                self.last_updated_rows = sorted(self._stale)
+                self.row_uploads += len(self.last_updated_rows)
                 self._stale.clear()
             else:
                 self.hits += 1

@@ -13,9 +13,10 @@ Hot-path invariants (the Coyote v2 "shell out of the datapath" story):
   * **Device-resident state.**  The KV pools, block tables, row lengths,
     last-sampled tokens, per-row temperatures, and the PRNG key all live
     on device.  Block tables are a cached :class:`DeviceBlockTable` view
-    owned by the MMU — rows are re-uploaded only when an alloc/extend/
-    free/evict delta changes a sequence's mapping (i.e. on page-boundary
-    crossings and slot churn), never per step.
+    owned by the MMU — the table goes to the device in one transfer
+    only when an alloc/extend/free/evict delta changes a sequence's
+    mapping (i.e. on page-boundary crossings and slot churn), never on
+    a step that changed nothing.
   * **Donation.**  ``decode_step_paged`` donates the pools and the
     decode-state buffers, so KV is updated in place instead of copied.
     ``self.pools`` / ``self.dev_lens`` / ``self.dev_tokens`` /

@@ -255,6 +255,96 @@ def test_device_block_table_is_incremental():
     assert (t2[0] == -1).all()
 
 
+def _one_seq_table():
+    """A 4-page MMU with seq 1 (6 tokens, 2 pages) bound to slot 0 of a
+    2-slot table, after its first view."""
+    mmu = MMU(MMUConfig(page_size=4, n_pages=4, host_pool_pages=16))
+    bt = mmu.block_table_device(n_slots=2, max_pages=8)
+    mmu.alloc_seq(1, 6)
+    bt.bind(0, 1)
+    bt.device_view()
+    return mmu, bt
+
+
+def test_device_block_table_hits_transfer_nothing():
+    mmu, bt = _one_seq_table()
+    t0, up0, hits0 = bt.device_view(), bt.uploads, bt.hits
+    mmu.extend_seq(1, 1)                     # 7 tokens, still 2 pages
+    for _ in range(3):
+        assert bt.device_view() is t0        # the cached array, no transfer
+    assert bt.uploads == up0
+    assert bt.hits == hits0 + 3
+    assert bt.last_updated_rows == []
+
+
+def _page_crossing(mmu, bt):
+    mmu.extend_seq(1, 3)                     # 9 tokens -> a 3rd page
+    return [1]
+
+
+def _bind(mmu, bt):
+    mmu.alloc_seq(2, 5)
+    bt.bind(1, 2)
+    return [1, 2]
+
+
+def _unbind(mmu, bt):
+    mmu.free_seq(1)
+    bt.unbind(0)
+    return [-1]
+
+
+def _eviction(mmu, bt):
+    mmu.alloc_seq(2, 12)                     # 3 pages of 4: evicts seq 1's
+    return [1]
+
+
+@pytest.mark.parametrize("event", [_page_crossing, _bind, _unbind,
+                                   _eviction],
+                         ids=["page_crossing", "bind", "unbind", "eviction"])
+def test_device_block_table_uploads_once_per_change(event):
+    """Each kind of change leaves the device view equal to the MMU's own
+    block table, for one transfer of the whole mirror."""
+    mmu, bt = _one_seq_table()
+    before, up0 = np.asarray(bt.device_view()).copy(), bt.uploads
+    seqs = event(mmu, bt)
+    t = np.asarray(bt.device_view())
+    assert bt.uploads == up0 + 1
+    assert not np.array_equal(t, before)
+    want = np.full((2, 8), -1, np.int32)
+    for i, sid in enumerate(seqs):
+        if sid >= 0:
+            want[i] = mmu.block_table([sid], 8)[0]
+    np.testing.assert_array_equal(t, want)
+    bt.device_view()                         # and nothing more after it
+    assert bt.uploads == up0 + 1
+
+
+def test_device_block_table_view_does_not_alias_the_host_mirror():
+    """A view already handed out keeps its values when the host mirror is
+    written in place (unbind, the refresh of a changed row).  The mirror
+    sits at the 64-byte alignment at which the CPU backend would take a
+    numpy buffer without copying it."""
+    mmu = MMU(MMUConfig(page_size=4, n_pages=64))
+    bt = mmu.block_table_device(n_slots=2, max_pages=8)
+    raw = np.empty(bt._host.nbytes + 64, np.uint8)
+    off = -raw.ctypes.data % 64
+    bt._host = raw[off:off + bt._host.nbytes].view(np.int32).reshape(2, 8)
+    bt._host[:] = -1
+    mmu.alloc_seq(1, 6)
+    mmu.alloc_seq(2, 9)
+    bt.bind(0, 1)
+    bt.bind(1, 2)
+    held = bt.device_view()
+    want = np.asarray(held).copy()
+    bt.unbind(0)
+    mmu.extend_seq(2, 4)                     # row 1 crosses into a 4th page
+    t = np.asarray(bt.device_view())
+    assert (t[0] == -1).all()
+    np.testing.assert_array_equal(t[1], mmu.block_table([2], 8)[0])
+    np.testing.assert_array_equal(np.asarray(held), want)
+
+
 def test_device_block_table_tracks_eviction():
     mmu = MMU(MMUConfig(page_size=4, n_pages=4, host_pool_pages=16))
     bt = mmu.block_table_device(n_slots=2, max_pages=8)

@@ -204,6 +204,38 @@ def test_tp2_token_parity_under_churn_and_eviction():
 
 
 @pytest.mark.slow
+def test_tp_block_table_upload_keeps_sharding_and_one_trace():
+    """A TP=2 engine's block table stays replicated on the mesh through
+    every upload (page crossings, binds and unbinds as rows turn over),
+    and the decode step, which keys on that sharding, traces once."""
+    script = _PREAMBLE + textwrap.dedent("""
+        from repro.serve.paged_model import TRACE_COUNTS
+        mesh = mesh_or_skip(1, 2)
+        cfg = get_config("smollm-135m").reduced()
+        params = T.init_params(jax.random.PRNGKey(0), cfg,
+                               dtype=jnp.float32)
+        mmu = MMU(MMUConfig(page_size=4, n_pages=128))
+        eng = ServingEngine(cfg, params, mmu, max_batch=2, max_len=64,
+                            seed=0, mesh=mesh)
+        for n in (5, 9, 3):
+            eng.submit(list(range(3, 3 + n)), max_new_tokens=10)
+        bt, steps_uploading = eng.block_table, 0
+        while eng.pending():
+            up0 = bt.uploads
+            eng.step()
+            steps_uploading += bt.uploads > up0
+            # the array the step read (another view here would take the
+            # next step's upload and its updated rows)
+            assert bt._dev.sharding == eng.tp.replicated, bt._dev.sharding
+        assert steps_uploading >= 4, steps_uploading
+        assert len(eng.completed) == 3
+        assert TRACE_COUNTS["decode_step_paged_tp"] == 1, TRACE_COUNTS
+        print("TP_TABLE_OK")
+    """)
+    _run_sub(script, "TP_TABLE_OK")
+
+
+@pytest.mark.slow
 def test_tp4_token_parity_and_heads_degradation():
     """TP=4: with 4 kv heads the full stack shards; with the stock
     reduced config (2 kv heads) attention statically degrades to
